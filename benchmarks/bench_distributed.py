@@ -104,6 +104,7 @@ print(json.dumps({
 def _run_child(n_devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"            # forced host devices, never the chip
     env["PYTHONPATH"] = os.path.join(_REPO, "src")
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=540)

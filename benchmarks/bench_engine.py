@@ -52,7 +52,7 @@ def _serve_stats(model, params, reqs, **cfg_overrides):
     from repro.engine import EngineConfig, InferenceEngine
 
     eng = InferenceEngine(model, params, EngineConfig(
-        page_size=16, probe=True, interpret=True, **cfg_overrides))
+        page_size=16, probe=True, **cfg_overrides))
     for prompt, max_new in reqs:
         eng.submit(prompt, max_new)
     done = eng.run()
@@ -103,7 +103,7 @@ def run():
     params = model.init(jax.random.PRNGKey(0))
     eng = InferenceEngine(model, params, EngineConfig(
         page_size=16, pool_pages=32, max_pages=3, buckets=(1, 2, 4),
-        probe=True, interpret=True))
+        probe=True))
     reqs = _trace(cfg.vocab_size)
     t0 = time.perf_counter()
     for prompt, max_new in reqs:

@@ -572,12 +572,14 @@ class DSEEngine:
 #                 device measurement produces), prunes against the
 #                 budget, and ranks;
 #   4. measure  — only the per-shape finalists (default + top priced)
-#                 run on the device, in workers sharing one EvalCache.
+#                 run on the device, in the parent, through the shared
+#                 EvalCache.
 #
-# Workers run in *spawned* processes: tasks carry only plain data,
-# spaces are rebuilt by name via ``search_spaces.sweep_space`` (bind
-# closures don't pickle), and the installed calibration state is
-# re-applied inside the worker.
+# Capture workers run in *spawned* processes pinned to the CPU backend:
+# a chip belongs to one process, and the parent holds it for phases 2
+# and 4. Tasks carry only plain data, spaces are rebuilt by name via
+# ``search_spaces.sweep_space`` (bind closures don't pickle), and the
+# installed calibration state is re-applied inside the worker.
 
 @dataclass
 class SweepShapeOutcome:
@@ -703,13 +705,21 @@ def _sweep_worker(task: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
-def _run_tasks(tasks: List[Dict[str, Any]], workers: int) -> List[Dict]:
+def _cpu_worker_init():
+    """Capture workers only trace: keep them off the accelerator, which
+    the parent process holds."""
+    import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
+def _run_captures(tasks: List[Dict[str, Any]], workers: int) -> List[Dict]:
     if workers > 1 and len(tasks) > 1:
         import multiprocessing as mp
         from concurrent.futures import ProcessPoolExecutor
         ctx = mp.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=workers,
-                                 mp_context=ctx) as ex:
+        with ProcessPoolExecutor(max_workers=workers, mp_context=ctx,
+                                 initializer=_cpu_worker_init) as ex:
             return list(ex.map(_sweep_worker, tasks))
     return [_sweep_worker(t) for t in tasks]
 
@@ -768,7 +778,7 @@ def run_sweep(kernel_id: str,
                           "walk": walk, "cache_dir": cache.root,
                           "space_fp": sfp, "calibration": ()})
     n_captured = sum(r.get("captured", 0)
-                     for r in _run_tasks(tasks, workers))
+                     for r in _run_captures(tasks, workers))
     price_wall = time.perf_counter() - t0
     traces = [store.load(kernel_id, sig, sfp)
               for sig, sfp in zip(shape_sigs, space_fps)]
@@ -826,7 +836,7 @@ def run_sweep(kernel_id: str,
             default_config=dict(sp.default)))
     sim_wall = time.perf_counter() - t0
 
-    # -- phase 4: measure only the finalists (workers, shared cache) ---
+    # -- phase 4: measure only the finalists (parent, shared cache) ----
     per_shape = max(2, top_k // max(len(shape_list), 1))
     t0 = time.perf_counter()
     tasks = []
@@ -840,20 +850,15 @@ def run_sweep(kernel_id: str,
             if cfg != sp.default:
                 finalists.append(cfg)
         finalists_per_shape.append(finalists)
-        # split each shape's finalists across (up to) two tasks so
-        # concurrent workers genuinely interleave on the shared cache
-        parts = (_chunked(finalists, max(1, (len(finalists) + 1) // 2))
-                 if workers > 1 else [finalists])
-        for part in parts:
-            tasks.append({"phase": "measure", "kernel": kernel_id,
-                          "shape": shape_list[i], "shape_idx": i,
-                          "configs": part, "steps": steps,
-                          "cache_dir": cache.root,
-                          "cycle_source": cycle_source,
-                          "calibration": calib_state})
+        tasks.append({"phase": "measure", "kernel": kernel_id,
+                      "shape": shape_list[i], "shape_idx": i,
+                      "configs": finalists, "steps": steps,
+                      "cache_dir": cache.root,
+                      "cycle_source": cycle_source,
+                      "calibration": calib_state})
     n_measured = n_cache_hits = 0
     measured: List[Dict[str, List]] = [{"rows": []} for _ in shape_list]
-    for res in _run_tasks(tasks, workers):
+    for res in map(_sweep_worker, tasks):
         n_measured += res["measurements"]
         n_cache_hits += res["cache_hits"]
         measured[res["shape_idx"]]["rows"].extend(res["rows"])

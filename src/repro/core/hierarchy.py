@@ -151,12 +151,7 @@ class Hierarchy:
 def _source_of(eqn) -> str:
     try:
         from jax._src import source_info_util
-        try:
-            # 0.4.x signature: user_frame(SourceInfo)
-            frame = source_info_util.user_frame(eqn.source_info)
-        except AttributeError:
-            # newer signature: user_frame(Traceback)
-            frame = source_info_util.user_frame(eqn.source_info.traceback)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
             return ""
         return f"{frame.file_name.rsplit('/', 1)[-1]}:{frame.start_line}"
@@ -251,12 +246,17 @@ def _extract_uncached(closed_jaxpr,
 
         for eqn in jaxpr.eqns:
             segs = normalize_stack(str(eqn.source_info.name_stack))
+            name = eqn.primitive.name
+            if (name == "pallas_call" and segs
+                    and segs[-1] == eqn.params.get("name")):
+                # pallas_call(name=...) opens a scope of that name around
+                # itself; the kernel node below already carries the name
+                segs = segs[:-1]
             node = prefix_node
             for s in segs:
                 node = _ensure(node, s)
                 if not node.source:
                     node.source = _source_of(eqn)
-            name = eqn.primitive.name
             if name in _LOOPS:
                 idx = counters.get(node.path + "#" + name, 0)
                 counters[node.path + "#" + name] = idx + 1

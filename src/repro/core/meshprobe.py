@@ -43,6 +43,7 @@ callbacks).
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -60,9 +61,19 @@ from repro.core.instrument import (Instrumenter, ProbeAssignment,
 from repro.core.oracle import Oracle, OracleCounters
 from repro.core.pragma import ProbeConfig, _select_probes
 from repro.core.streaming import StreamAggregator
-from repro.distributed import compat
 from repro.launch.collectives import (PRIMITIVE_KINDS, CollectiveSite,
                                       jaxpr_collectives)
+
+
+@contextlib.contextmanager
+def extend_axis_env(sizes: Dict[str, int]):
+    """Bind mesh axis names for tracing outside ``shard_map``, so
+    ``jax.make_jaxpr`` can trace a per-shard function that uses
+    collectives (``lax.psum(x, "dev")`` …) — the mesh-probe builder
+    traces the shard body once this way."""
+    from jax._src.core import extend_axis_env_nd
+    with extend_axis_env_nd(list(sizes.items())):
+        yield
 
 
 def _is_spec_leaf(x) -> bool:
@@ -322,7 +333,7 @@ class MeshProbedFunction:
             store["out_tree"] = out_tree
             return flat_out
 
-        with compat.extend_axis_env(self.axis_sizes), \
+        with extend_axis_env(self.axis_sizes), \
                 cm.collective_axis_sizes(self.axis_sizes):
             self._closed = jax.make_jaxpr(flat_fn)(*shard_avals)
             t1 = time.perf_counter()
@@ -370,7 +381,7 @@ class MeshProbedFunction:
                 outs, st = interp.run(closed, list(flat_args), st)
             return tuple(outs), {k: v[None] for k, v in st.items()}
 
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             shard_body, mesh=self.mesh,
             in_specs=(state_specs,) + tuple(self._flat_in_specs),
             out_specs=(tuple(self._flat_out_specs), state_specs),
@@ -428,7 +439,7 @@ class MeshProbedFunction:
             out = self.fn(*jax.tree_util.tree_unflatten(self._in_tree,
                                                         flat_args))
             return tuple(jax.tree_util.tree_leaves(out))
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             flat_fn, mesh=self.mesh, in_specs=tuple(self._flat_in_specs),
             out_specs=tuple(self._flat_out_specs),
             check_vma=self.check_specs)

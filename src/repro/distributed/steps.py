@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ShapeConfig, TrainConfig
-from repro.distributed import compat
 from repro.models.model import Model
 from repro.optim import adamw, compression
 from repro.optim.schedule import make_schedule
@@ -91,7 +90,7 @@ def build_train_step(model: Model, tcfg: TrainConfig) -> Callable:
     def compressed_grads_of(params, batch, residual):
         """Pod-local grads + int8 error-feedback ring exchange over the
         pod axis. data/model axes stay auto-sharded inside."""
-        mesh = compat.get_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         n_pods = mesh.shape["pod"]
         perm = [(i, (i + 1) % n_pods) for i in range(n_pods)]
 
@@ -127,7 +126,7 @@ def build_train_step(model: Model, tcfg: TrainConfig) -> Callable:
         rep_r = jax.tree_util.tree_map(lambda _: P(), residual)
         metrics_spec = {"nll": P()} if k > 1 else \
             {"nll": P(), "z_loss": P(), "aux_loss": P()}
-        return compat.shard_map(
+        return jax.shard_map(
             pod_local, mesh=mesh,
             in_specs=(rep_p, in_batch_specs, rep_r),
             out_specs=(P(), metrics_spec, rep_p, rep_r),

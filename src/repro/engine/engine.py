@@ -117,7 +117,6 @@ class EngineConfig:
     probe_targets: Tuple[str, ...] = ("",)
     probe_max_probes: int = 16
     prefix_cache: bool = True
-    interpret: Optional[bool] = None
     prefill_chunk_pages: int = 0      # 0 = whole-prompt prefill (DSE axis)
     evict_policy: str = "lru"         # "lru" | "clear" (legacy)
     donate: Optional[bool] = None     # None = auto (off probe / off CPU)
@@ -214,8 +213,7 @@ class InferenceEngine:
         else:
             fn = build_paged_decode(
                 self.model, size, c.max_pages, c.page_size,
-                use_kernel=c.use_kernel, pages_per_step=c.pages_per_step,
-                interpret=c.interpret)
+                use_kernel=c.use_kernel, pages_per_step=c.pages_per_step)
         if c.probe:
             from repro.core import ProbeConfig, ProbeSession
             tag = size if isinstance(size, int) \

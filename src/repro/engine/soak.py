@@ -76,7 +76,7 @@ def soak(*, arch: str = "tinyllama-1.1b", waves: int = 3,
     eng = InferenceEngine(model, params, EngineConfig(
         page_size=16, pool_pages=pool, max_pages=8, buckets=(1, 2, 4),
         use_kernel=use_kernel, pages_per_step=2, probe=probe,
-        prefill_chunk_pages=chunk, interpret=True))
+        prefill_chunk_pages=chunk))
     rng = np.random.default_rng(seed)
     # one full page each, so later waves hit the prefix cache
     prefixes = [rng.integers(0, cfg.vocab_size, 16).tolist()

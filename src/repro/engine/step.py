@@ -278,9 +278,10 @@ def _paged_attn_xla(lp, x, kp, vp, pages, pos, cfg, s_max: int,
 
 
 def _paged_attn_kernel(lp, x, kp, vp, pages, pos, cfg, s_max: int,
-                       page_size: int, pages_per_step: int,
-                       interpret: bool):
-    """Pallas paged-attention attend (bit-identical to the XLA path)."""
+                       page_size: int, pages_per_step: int):
+    """Pallas paged-attention attend (bit-identical to the XLA path),
+    interpreted on the CPU and compiled on the TPU."""
+    from repro.kernels.ops import _interpret_default
     from repro.kernels.paged_attention import paged_attention
     positions = pos[:, None]
     q, k_new, v_new = _project_qkv(lp, x, cfg, positions)
@@ -297,14 +298,14 @@ def _paged_attn_kernel(lp, x, kp, vp, pages, pos, cfg, s_max: int,
         kp = kp.at[pidx, slot].set(k_new[:, 0].astype(kp.dtype))
         vp = vp.at[pidx, slot].set(v_new[:, 0].astype(vp.dtype))
     o = paged_attention(qg[:, 0], kp, vp, pages, pos,
-                        pages_per_step=pages_per_step, interpret=interpret)
+                        pages_per_step=pages_per_step,
+                        interpret=_interpret_default())
     return o, kp, vp
 
 
 def build_paged_decode(model, batch_size: int, n_pages: int,
                        page_size: int, *, use_kernel: bool = True,
-                       pages_per_step: int = 1,
-                       interpret: bool | None = None) -> Callable:
+                       pages_per_step: int = 1) -> Callable:
     """Batched single-token decode over the paged pool.
 
     fn(params, pool_k, pool_v, batch) with batch = {"tokens": (B, 1),
@@ -313,15 +314,11 @@ def build_paged_decode(model, batch_size: int, n_pages: int,
     """
     cfg = model.cfg
     s_max = n_pages * page_size
-    if interpret is None:
-        from repro.kernels.ops import _interpret_default
-        interpret = _interpret_default()
 
     def attend(lp, x, kp, vp, pages, pos):
         if use_kernel:
             return _paged_attn_kernel(lp, x, kp, vp, pages, pos, cfg,
-                                      s_max, page_size, pages_per_step,
-                                      interpret)
+                                      s_max, page_size, pages_per_step)
         return _paged_attn_xla(lp, x, kp, vp, pages, pos, cfg, s_max,
                                page_size)
 

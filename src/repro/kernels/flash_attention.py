@@ -30,18 +30,11 @@ NEG_INF = float("-inf")
 _SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 
-def _compiler_params(interpret: bool):
-    if interpret:
-        return None
-    if hasattr(pltpu, "CompilerParams"):             # jax >= 0.7 style
-        return pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
-    return dict(mosaic=dict(dimension_semantics=_SEMANTICS))
-
-
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, probe_ref,
-                  acc_ref, m_ref, l_ref,
-                  *, block_q: int, block_k: int, pipeline: int, causal: bool,
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
+                  block_q: int, block_k: int, pipeline: int, causal: bool,
                   sm_scale: float, with_probe: bool):
+    probe_ref = rest[0] if with_probe else None
+    acc_ref, m_ref, l_ref = rest[-3:]
     iq = pl.program_id(2)
     ig = pl.program_id(3)            # kv DMA-group index (pipeline blocks)
     ng = pl.num_programs(3)
@@ -70,11 +63,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, probe_ref,
                 if causal else True
 
             if with_probe:
-                # control-event counters: [0]=blocks visited,
-                # [1]=blocks computed
-                probe_ref[0, 0, 0, 0] += 1
-                probe_ref[0, 0, 0, 1] += jnp.where(
-                    should_compute, 1, 0).astype(probe_ref.dtype)
+                # control-event counters in one (8, 128) tile, row 0:
+                # lane 0 = blocks visited, lane 1 = blocks computed
+                tile = probe_ref.shape[2:]
+                row = jax.lax.broadcasted_iota(jnp.int32, tile, 0)
+                lane = jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+                computed = jnp.where(should_compute, 1, 0)
+                probe_ref[0, 0] += jnp.where(
+                    row == 0, jnp.where(lane == 0, 1,
+                                        jnp.where(lane == 1, computed, 0)),
+                    0).astype(probe_ref.dtype)
 
             @pl.when(should_compute)
             def _compute(p=p, ik=ik):
@@ -90,19 +88,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, probe_ref,
                     k_pos = ik * block_k + jax.lax.broadcasted_iota(
                         jnp.int32, (block_q, block_k), 1)
                     s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-                m_prev = m_ref[...]
-                m_new = jnp.maximum(m_prev, s.max(axis=-1))
+                m_prev = m_ref[...]                            # (bq, 1)
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
                 m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-                p_ = jnp.exp(s - m_safe[:, None])
+                p_ = jnp.exp(s - m_safe)
                 corr = jnp.where(jnp.isneginf(m_prev), 0.0,
                                  jnp.exp(m_prev - m_safe))
-                l_ref[...] = l_ref[...] * corr + p_.sum(axis=-1)
+                l_ref[...] = l_ref[...] * corr + p_.sum(axis=-1,
+                                                        keepdims=True)
                 v = v_ref[0, 0, p * block_k:(p + 1) * block_k].astype(
                     jnp.float32)                               # (bk, D)
                 pv = jax.lax.dot_general(
                     p_, v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                acc_ref[...] = acc_ref[...] * corr[:, None] + pv
+                acc_ref[...] = acc_ref[...] * corr + pv
                 m_ref[...] = m_new
 
     with jax.named_scope("finalize"):
@@ -115,7 +114,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, probe_ref,
         @pl.when(ig == last_g)
         def _finalize():
             l = jnp.maximum(l_ref[...], 1e-37)
-            o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+            o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -157,9 +156,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     out_shape = [jax.ShapeDtypeStruct((B, H, S, D), q.dtype)]
     out_specs = [pl.BlockSpec((1, 1, block_q, D),
                               lambda b, h, i, j: (b, h, i, 0))]
-    out_shape.append(jax.ShapeDtypeStruct((B, H, nq, 2), jnp.int32))
-    out_specs.append(pl.BlockSpec((1, 1, 1, 2),
-                                  lambda b, h, i, j: (b, h, i, 0)))
+    if with_probe:
+        # one (8, 128) int32 tile per q block keeps the probe block on
+        # the TPU tiling; the counters are row 0, lanes 0-1
+        out_shape.append(jax.ShapeDtypeStruct((B, H, nq * 8, 128),
+                                              jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 8, 128),
+                                      lambda b, h, i, j: (b, h, i, 0)))
 
     grid = (B, H, nq, ng)
     res = pl.pallas_call(
@@ -177,13 +180,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
-            pltpu.VMEM((block_q,), jnp.float32),     # m
-            pltpu.VMEM((block_q,), jnp.float32),     # l
+            pltpu.VMEM((block_q, 1), jnp.float32),   # m
+            pltpu.VMEM((block_q, 1), jnp.float32),   # l
         ],
-        compiler_params=_compiler_params(interpret),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
+        name="flash_kernel",
     )(q, k, v)
-    out, probe = res
     if with_probe:
-        return out, probe
-    return out
+        out, probe = res
+        return out, probe.reshape(B, H, nq, 8, 128)[:, :, :, 0, :2]
+    return res[0]

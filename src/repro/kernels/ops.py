@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends the kernels execute in ``interpret=True`` mode (the
+On the CPU backend the kernels execute in ``interpret=True`` mode (the
 kernel body runs as traced JAX ops — bit-identical math, CPU-validatable),
-which is how the test suite sweeps shapes/dtypes against ``ref.py``.
+which is how the test suite sweeps shapes/dtypes against ``ref.py``. On
+the TPU they compile; any other backend is an error, so a run meant for
+the chip never lands in the interpreter unnoticed.
 
 Tile/pipeline arguments left as ``None`` resolve through the tuned-
 defaults registry (``repro.kernels.tuning``), so a ``repro.tune`` run
@@ -21,7 +23,12 @@ from repro.kernels import tuning
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend in ("tpu", "cpu"):
+        return backend == "cpu"
+    raise RuntimeError(f"Pallas TPU kernels run compiled on 'tpu' or "
+                       f"interpreted on 'cpu'; the default backend is "
+                       f"{backend!r}")
 
 
 def _fit_block(size: int, want: int) -> int:

@@ -39,56 +39,51 @@ DEFAULT_PAGES_PER_STEP = 1
 _SEMANTICS = ("parallel", "arbitrary")
 
 
-def _compiler_params(interpret: bool):
-    if interpret:
-        return None
-    if hasattr(pltpu, "CompilerParams"):             # jax >= 0.7 style
-        return pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
-    return dict(mosaic=dict(dimension_semantics=_SEMANTICS))
-
-
 def _paged_kernel(pages_ref, pos_ref, q_ref, *rest, pages_per_step: int,
                   page_size: int, n_pages: int, sm_scale: float):
     k_refs = rest[:pages_per_step]
     v_refs = rest[pages_per_step:2 * pages_per_step]
     o_ref = rest[2 * pages_per_step]
     k_scr, v_scr = rest[2 * pages_per_step + 1:]
-    # NB: every program_id/num_programs read happens at the kernel's
-    # top level — inside a pl.when body they are not substituted by the
-    # interpret-mode evaluator (jax 0.4.x).
+    # every program_id/num_programs read happens at the kernel's top
+    # level, outside the pl.when body
     b = pl.program_id(0)
     j = pl.program_id(1)
     n_steps = pl.num_programs(1)
     s_max = n_pages * page_size
+    kv, g, _ = q_ref.shape[1:]
 
     with jax.named_scope("copy_pages"):
         # one grid step stages `pages_per_step` pool pages into the
-        # dense VMEM scratch (statically unrolled DMA group)
+        # per-kv-head (kv, s_max, hd) VMEM scratch (statically unrolled)
         for i in range(pages_per_step):
-            k_scr[j * pages_per_step + i] = k_refs[i][...]
-            v_scr[j * pages_per_step + i] = v_refs[i][...]
+            off = pl.multiple_of((j * pages_per_step + i) * page_size,
+                                 page_size)
+            for h in range(kv):
+                k_scr[h, pl.ds(off, page_size), :] = k_refs[i][0, :, h, :]
+                v_scr[h, pl.ds(off, page_size), :] = v_refs[i][0, :, h, :]
 
     with jax.named_scope("attend"):
         @pl.when(j == n_steps - 1)
         def _attend():
-            # dense-shape global softmax: identical einsum shapes and
-            # reduction lengths as the XLA reference — not flash
-            kv, g, hd = q_ref.shape[1:]
-            qg = q_ref[...][:, None]                 # (1, 1, kv, g, hd)
-            kd = k_scr[...].reshape(1, s_max, kv, hd)
-            vd = v_scr[...].reshape(1, s_max, kv, hd)
-            s = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.bfloat16),
-                           kd.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32) * sm_scale
-            mask = jnp.arange(s_max)[None, :] <= pos_ref[b][None, None]
-            s = jnp.where(mask[:, None, None, None, :], s, -jnp.inf)
-            m = s.max(axis=-1, keepdims=True)
-            p = jnp.exp(s - m)
-            l = p.sum(axis=-1, keepdims=True)
-            o = jnp.einsum("bkgqs,bskh->bkgqh", (p / l).astype(jnp.bfloat16),
-                           vd.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)
-            o_ref[...] = o[:, :, :, 0]
+            # dense-length global softmax, one 2-D dot pair per kv head:
+            # the same products and reduction lengths as the XLA
+            # reference's batched einsums — not flash
+            mask = jax.lax.broadcasted_iota(
+                jnp.int32, (g, s_max), 1) <= pos_ref[b]
+            for h in range(kv):
+                s = jax.lax.dot_general(
+                    q_ref[0, h].astype(jnp.bfloat16),
+                    k_scr[h].astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                s = jnp.where(mask, s, -jnp.inf)
+                m = s.max(axis=-1, keepdims=True)
+                p = jnp.exp(s - m)
+                l = p.sum(axis=-1, keepdims=True)
+                o_ref[0, h] = jax.lax.dot_general(
+                    (p / l).astype(jnp.bfloat16),
+                    v_scr[h].astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
 
 def paged_attention(q, pool_k, pool_v, pages, pos, *,
@@ -141,8 +136,8 @@ def paged_attention(q, pool_k, pool_v, pages, pos, *,
         ),
         out_specs=pl.BlockSpec((1, kv, g, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((n_pages, 1, page_size, kv, hd), pool_k.dtype),
-            pltpu.VMEM((n_pages, 1, page_size, kv, hd), pool_v.dtype),
+            pltpu.VMEM((kv, n_pages * page_size, hd), pool_k.dtype),
+            pltpu.VMEM((kv, n_pages * page_size, hd), pool_v.dtype),
         ],
     )
     kernel = functools.partial(
@@ -153,6 +148,7 @@ def paged_attention(q, pool_k, pool_v, pages, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kv, g, hd), jnp.float32),
-        compiler_params=_compiler_params(interpret),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
+        name="paged_kernel",
     )(pages, pos, q, *pools)

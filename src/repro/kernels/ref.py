@@ -6,6 +6,8 @@ plays for RealProbe in the paper.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -29,6 +31,32 @@ def flash_attention_ref(q, k, v, *, causal: bool = True):
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
     return o.astype(q.dtype)
+
+
+def paged_attention_ref(q, pool_k, pool_v, pages, pos):
+    """Dense-gather paged decode attention.
+
+    q: (B, kv_heads, q_per_kv, head_dim); pool_k, pool_v: (num_pool_pages,
+    page_size, kv_heads, head_dim); pages: (B, n_pages) page-table rows;
+    pos: (B,) current positions (slots > pos are masked). Returns
+    (B, kv_heads, q_per_kv, head_dim) float32. bf16 operands with f32
+    accumulation, one global softmax: the math the engine's XLA decode
+    runs, and the kernel's exactness contract.
+    """
+    B, kv, _, hd = q.shape
+    s_max = pages.shape[1] * pool_k.shape[1]
+    kd = pool_k[pages].reshape(B, s_max, kv, hd)
+    vd = pool_v[pages].reshape(B, s_max, kv, hd)
+    s = jnp.einsum("bkgh,bskh->bkgs", q.astype(jnp.bfloat16),
+                   kd.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(hd))
+    mask = jnp.arange(s_max)[None, :] <= pos[:, None]
+    s = jnp.where(mask[:, None, None, :], s, -jnp.inf)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    return jnp.einsum("bkgs,bskh->bkgh",
+                      (p / p.sum(-1, keepdims=True)).astype(jnp.bfloat16),
+                      vd.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
 
 
 def ssd_ref(x, a, b, c):

@@ -22,15 +22,7 @@ from jax.experimental.pallas import tpu as pltpu
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _compiler_params(interpret: bool):
-    if interpret:
-        return None
-    if hasattr(pltpu, "CompilerParams"):
-        return pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
-    return dict(mosaic=dict(dimension_semantics=_SEMANTICS))
-
-
-def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_scratch,
+def _ssd_kernel(x_ref, acs_ref, b_ref, c_ref, y_ref, state_scratch,
                 *, chunk: int, pipeline: int):
     ci = pl.program_id(2)
 
@@ -50,19 +42,22 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_scratch,
         with jax.named_scope("sub_chunk"):
             lo, hi = p * sub, (p + 1) * sub
             x = x_ref[0, 0, lo:hi].astype(jnp.float32)     # (Q, P)
-            a = a_ref[0, 0, lo:hi].astype(jnp.float32)     # (Q,)
+            # sub-chunk-local cumsum of a, as a column (from the wrapper)
+            a_cs = acs_ref[0, 0, lo:hi]                    # (Q, 1)
             b = b_ref[0, 0, lo:hi].astype(jnp.float32)     # (Q, N)
             c = c_ref[0, 0, lo:hi].astype(jnp.float32)     # (Q, N)
 
-            a_cs = jnp.cumsum(a)                           # (Q,)
             # intra-chunk:
             #   y_diag[q] = sum_{k<=q} exp(a_cs[q]-a_cs[k]) (c_q.b_k) x_k
             cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            seg = a_cs[:, None] - a_cs[None, :]
             qi = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
             ki = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-            decay = jnp.where(qi >= ki, jnp.exp(seg), 0.0)
+            # the row form of a_cs, read off the diagonal (exact: one
+            # nonzero term per column)
+            a_row = jnp.sum(jnp.where(qi == ki, a_cs, 0.0), axis=0,
+                            keepdims=True)                 # (1, Q)
+            decay = jnp.where(qi >= ki, jnp.exp(a_cs - a_row), 0.0)
             y_diag = jax.lax.dot_general(cb * decay, x,
                                          (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
@@ -70,14 +65,23 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_scratch,
             state = state_scratch[...]
             y_off = jax.lax.dot_general(c, state, (((1,), (1,)), ((), ())),
                                         preferred_element_type=jnp.float32)
-            y_off = y_off * jnp.exp(a_cs)[:, None]
+            y_off = y_off * jnp.exp(a_cs)
             y_ref[0, 0, lo:hi] = (y_diag + y_off).astype(y_ref.dtype)
             # state': exp(a_cs[-1]) * state + sum_k d_k x_k b_k^T
-            decay_states = jnp.exp(a_cs[-1] - a_cs)        # (Q,)
-            xb = jax.lax.dot_general(x * decay_states[:, None], b,
+            a_last = a_cs[sub - 1:sub]                     # (1, 1)
+            decay_states = jnp.exp(a_last - a_cs)          # (Q, 1)
+            xb = jax.lax.dot_general(x * decay_states, b,
                                      (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            state_scratch[...] = state * jnp.exp(a_cs[-1]) + xb
+            # exp(a_cs[-1]) as a (1, N) row, read off the last row of a
+            # lane broadcast: Mosaic cannot broadcast (1, 1) to (P, N)
+            n_state = xb.shape[1]
+            rows = jax.lax.broadcasted_iota(jnp.int32, (sub, n_state), 0)
+            last_row = jnp.sum(
+                jnp.where(rows == sub - 1,
+                          jnp.broadcast_to(a_cs, (sub, n_state)), 0.0),
+                axis=0, keepdims=True)                     # (1, N)
+            state_scratch[...] = state * jnp.exp(last_row) + xb
 
 
 def ssd_scan(x, a, b, c, *, chunk: int = 256, pipeline: int = 1,
@@ -101,6 +105,11 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, pipeline: int = 1,
     if pipeline < 1 or chunk % pipeline:
         raise ValueError(f"chunk {chunk} % pipeline {pipeline}")
     nc = L // chunk
+    sub = chunk // pipeline
+    # the per-sub-chunk cumsum of a runs here in XLA: it reaches the
+    # kernel as a (L, 1) column, a block layout the TPU tiling accepts
+    a_cs = jnp.cumsum(a.astype(jnp.float32).reshape(B, H, L // sub, sub),
+                      axis=-1).reshape(B, H, L, 1)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk, pipeline=pipeline)
     grid = (B, H, nc)
@@ -109,7 +118,7 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, pipeline: int = 1,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda bi, h, ci: (bi, h, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, h, ci: (bi, h, ci)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda bi, h, ci: (bi, h, ci, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda bi, h, ci: (bi, h // e, ci, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda bi, h, ci: (bi, h // e, ci, 0)),
         ],
@@ -117,6 +126,7 @@ def ssd_scan(x, a, b, c, *, chunk: int = 256, pipeline: int = 1,
                                lambda bi, h, ci: (bi, h, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, L, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-    )(x, a, b, c)
+        name="ssd_kernel",
+    )(x, a_cs, b, c)

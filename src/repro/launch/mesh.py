@@ -19,10 +19,7 @@ from typing import Sequence, Tuple
 
 import jax
 
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                     # 0.4.x
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _factorizations(n: int, k: int) -> Tuple[Tuple[int, ...], ...]:
@@ -61,14 +58,7 @@ def make_mesh(shape, axes):
     """Generic validated mesh (small CPU meshes for tests and probing)."""
     shape, axes = tuple(int(s) for s in shape), tuple(axes)
     validate_mesh_shape(shape, axes)
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-    return Mesh(mesh_utils.create_device_mesh(shape), axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def probe_axis_names(shape) -> Tuple[str, ...]:

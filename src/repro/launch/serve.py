@@ -14,6 +14,7 @@ under a live ``ProbeSession`` with streaming telemetry.
 from __future__ import annotations
 
 import argparse
+import math
 import time
 from typing import Optional, Tuple
 
@@ -53,26 +54,32 @@ def _mesh_decode_session(model, shape, mesh_shape, frontend: bool,
         window_steps=window_steps, bus=bus, source="serve/mesh")
 
 
+def engine_config(batch: int, prompt_len: int, max_new: int, **knobs):
+    """The ``EngineConfig`` that ``serve`` runs ``batch`` requests of
+    ``prompt_len`` + ``max_new`` tokens with: 16-token pages, a page
+    table one request wide, a pool for the whole batch, and decode
+    buckets (1, batch). ``knobs`` are the remaining config fields."""
+    from repro.engine import EngineConfig
+    page = 16
+    max_pages = max(1, math.ceil((prompt_len + max_new - 1) / page))
+    return EngineConfig(page_size=page, pool_pages=batch * max_pages + 2,
+                        max_pages=max_pages,
+                        buckets=(1, batch) if batch > 1 else (1,), **knobs)
+
+
 def _engine_serve(model, params, key, *, batch: int, prompt_len: int,
                   max_new: int, profile: bool,
                   profile_targets: Tuple[str, ...],
                   profile_max_probes: int, engine_kernel: bool,
-                  prefill_chunk: int = 0, donate: Optional[bool] = None,
-                  bus=None):
+                  prefill_chunk: int = 0,
+                  donate: Optional[bool] = None, bus=None):
     """Serve ``batch`` random prompts through the continuous-batching
     engine (one request per row, decode bucketed at the batch size)."""
-    import math
-
-    from repro.engine import EngineConfig, InferenceEngine
+    from repro.engine import InferenceEngine
     cfg = model.cfg
-    page = 16
-    max_pages = max(1, math.ceil((prompt_len + max_new - 1) / page))
-    eng = InferenceEngine(model, params, EngineConfig(
-        page_size=page, pool_pages=batch * max_pages + 2,
-        max_pages=max_pages,
-        buckets=(1, batch) if batch > 1 else (1,),
-        use_kernel=engine_kernel, probe=profile,
-        probe_targets=profile_targets,
+    eng = InferenceEngine(model, params, engine_config(
+        batch, prompt_len, max_new, use_kernel=engine_kernel,
+        probe=profile, probe_targets=profile_targets,
         probe_max_probes=profile_max_probes,
         prefill_chunk_pages=prefill_chunk, donate=donate), bus=bus)
     tokens = jax.random.randint(key, (batch, prompt_len), 0,
@@ -232,6 +239,8 @@ def serve(arch: str = "tinyllama-1.1b", *, smoke: bool = True,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
+    ap.add_argument("--full", action="store_true",
+                    help="full config (needs real hardware)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
@@ -264,8 +273,11 @@ def main():
                     help="expose live telemetry over HTTP on this port "
                          "(0 = OS-assigned; prints the bound URL)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import parse_mesh_arg
-    toks = serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
+    enable_compile_cache()
+    toks = serve(args.arch, smoke=not args.full, batch=args.batch,
+                 prompt_len=args.prompt_len,
                  max_new=args.max_new, profile=args.profile,
                  profile_targets=tuple(args.profile_targets.split(",")),
                  profile_every=args.profile_every,

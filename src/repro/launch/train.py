@@ -10,6 +10,7 @@ suite on small meshes.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 from typing import Optional
 
@@ -113,8 +114,9 @@ def train(arch: str = "tinyllama-1.1b", *, smoke: bool = True,
 
     ctx = shd.axis_rules(rules, mesh)
     history = []
-    from repro.distributed import compat
-    with compat.mesh_context(mesh), ctx:
+    mesh_ctx = jax.set_mesh(mesh) if mesh is not None \
+        else contextlib.nullcontext()
+    with mesh_ctx, ctx:
         t0 = time.time()
         for step in range(start_step, steps):
             batch_np = pipe.batch_at(step)
@@ -190,6 +192,8 @@ def main():
                     help="expose live telemetry over HTTP on this port "
                          "(0 = OS-assigned; prints the bound URL)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     train(args.arch, smoke=not args.full, steps=args.steps,
           batch=args.batch, seq=args.seq,
           probe_targets=(tuple(args.probe_targets.split(","))

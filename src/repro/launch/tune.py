@@ -150,6 +150,8 @@ def main(argv=None) -> int:
     ap.add_argument("--no-reuse", action="store_true",
                     help="sweep: ignore stored trace artifacts")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     kernels = list(KERNELS) if args.kernel == "all" else [args.kernel]
     cache = EvalCache(args.cache_dir)

@@ -62,7 +62,7 @@ def _init_leaf(p: Param, key, dtype):
         dt = jnp.exp(u * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
     fan_in = p.shape[0] if p.init == "embed" else (
-        int(jnp.prod(jnp.array(p.shape[:-1]))) if len(p.shape) > 1 else p.shape[0])
+        math.prod(p.shape[:-1]) if len(p.shape) > 1 else p.shape[0])
     std = p.scale / math.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, p.shape, jnp.float32) * std).astype(dtype)
 

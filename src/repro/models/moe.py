@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed import compat
 from repro.distributed import sharding as shd
 from repro.models.layers import Param
 
@@ -205,7 +204,7 @@ def moe_apply(params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
             out, aux = _moe_local(x, params["router"], params["wi"],
                                   params["wg"], params["wo"], cfg)
         else:
-            mesh = compat.get_mesh()
+            mesh = jax.sharding.get_abstract_mesh()
             rules = shd.filter_rules(rules, mesh)
             batch = rules.get("batch")
             batch_axes = ((batch,) if isinstance(batch, str) else
@@ -225,7 +224,7 @@ def moe_apply(params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
                 else:
                     wo_f = wo_
                 return body(x_, rw, wi_, wg_, wo_f)
-            out, aux = compat.shard_map(
+            out, aux = jax.shard_map(
                 wrapped, mesh=mesh,
                 in_specs=(x_spec, P(None, None), w_spec, w_spec, wo_spec),
                 out_specs=(x_spec, P()),

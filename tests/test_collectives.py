@@ -66,7 +66,7 @@ def test_parse_hlo_variadic_tuple_and_token():
 
 
 def test_jaxpr_collectives_joins_scopes_and_groups():
-    from repro.distributed import compat
+    from repro.core.meshprobe import extend_axis_env
 
     def fn(x):
         with jax.named_scope("sync"):
@@ -76,7 +76,7 @@ def test_jaxpr_collectives_joins_scopes_and_groups():
         return jnp.sum(s) + jnp.sum(g)
 
     sizes = {"a": 2, "b": 4}
-    with compat.extend_axis_env(sizes):
+    with extend_axis_env(sizes):
         closed = jax.make_jaxpr(fn)(jnp.ones((8,), jnp.float32))
     sites = {s.primitive: s for s in
              jaxpr_collectives(closed.jaxpr, sizes)}
@@ -95,12 +95,12 @@ def test_costmodel_collective_term_responds_to_mesh_size():
     bytes (mesh-size sensitive); without, the legacy operand-bytes
     fallback keeps old numbers (baseline compatibility)."""
     from repro.core import costmodel as cm
-    from repro.distributed import compat
+    from repro.core.meshprobe import extend_axis_env
 
     def fn(x):
         return jax.lax.psum(x, "dev")
 
-    with compat.extend_axis_env({"dev": 8}):
+    with extend_axis_env({"dev": 8}):
         closed = jax.make_jaxpr(fn)(jnp.ones((4096,), jnp.float32))
     (eqn,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "psum"]
     legacy = cm.eqn_cost(eqn)

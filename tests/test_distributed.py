@@ -16,6 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_sub(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"            # forced host devices, never the chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=540)
@@ -32,7 +33,6 @@ from repro.models.model import Model
 from repro.distributed import sharding as shd
 from repro.distributed.steps import build_train_step, build_decode_step
 from repro.launch.mesh import make_mesh
-from repro.distributed.compat import mesh_context
 from repro.optim import adamw
 """
 
@@ -63,7 +63,7 @@ psh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), pspecs,
                              is_leaf=lambda x: isinstance(x, P))
 bsh = {kk: NamedSharding(mesh, P("data")) for kk in batch}
 osh = adamw.AdamWState(step=NamedSharding(mesh, P()), mu=psh, nu=psh)
-with mesh_context(mesh), shd.axis_rules(rules, mesh):
+with jax.set_mesh(mesh), shd.axis_rules(rules, mesh):
     p2, o2, m2 = jax.jit(step, in_shardings=(psh, osh, bsh))(params, opt, batch)
 print(json.dumps({"l1": float(m1["loss"]), "l2": float(m2["loss"])}))
 """
@@ -85,7 +85,7 @@ step = build_decode_step(model)
 l1, _, _ = jax.jit(step)(params, cache, batch)
 mesh = make_mesh((2, 4), ("data", "model"))
 rules = shd.filter_rules(shd.SERVE_RULES, mesh)
-with mesh_context(mesh), shd.axis_rules(rules, mesh):
+with jax.set_mesh(mesh), shd.axis_rules(rules, mesh):
     l2, _, _ = jax.jit(step)(params, model.init_cache(shape), batch)
 V = cfg.vocab_size   # pad columns are -inf by design
 l1, l2 = l1[:, :V], l2[:, :V]
@@ -108,7 +108,7 @@ batch = {"tokens": jax.random.randint(k, (B, S), 0, cfg.vocab_size),
 l1, _ = jax.jit(model.loss_fn)(params, batch)
 mesh = make_mesh((2, 4), ("data", "model"))
 rules = shd.filter_rules(shd.TRAIN_RULES, mesh)
-with mesh_context(mesh), shd.axis_rules(rules, mesh):
+with jax.set_mesh(mesh), shd.axis_rules(rules, mesh):
     l2, _ = jax.jit(model.loss_fn)(params, batch)
 print(json.dumps({"l1": float(l1), "l2": float(l2)}))
 """
@@ -120,9 +120,8 @@ print(json.dumps({"l1": float(l1), "l2": float(l2)}))
 
 def test_int8_ef_grad_compression_pod_axis():
     """Compressed cross-pod exchange: loss finite, params update, and
-    the result stays close to the uncompressed step. On jax 0.4.x this
-    exercises compat.shard_map's full-manual fallback (partial-manual
-    regions abort the old SPMD partitioner)."""
+    the result stays close to the uncompressed step (a partial-manual
+    shard_map over the pod axis)."""
     code = PREAMBLE + """
 from repro.optim import compression
 cfg = smoke_config("tinyllama-1.1b").replace(compute_dtype="float32")
@@ -139,7 +138,7 @@ opt = adamw.init(params, cfg.moment_dtype)
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = shd.filter_rules(shd.TRAIN_RULES, mesh)
 res = compression.init_residual(params)
-with mesh_context(mesh), shd.axis_rules(rules, mesh):
+with jax.set_mesh(mesh), shd.axis_rules(rules, mesh):
     step0 = build_train_step(model, tcfg0)
     p0, _, m0 = jax.jit(step0)(params, opt, batch)
     step1 = build_train_step(model, tcfg1)

@@ -243,8 +243,11 @@ def test_load_cache_into_registry(cache):
 
 # ------------------------------------------------------------ CLI
 
-def test_tune_cli_smoke(tmp_path, capsys):
+def test_tune_cli_smoke(tmp_path, capsys, monkeypatch):
     from repro.launch.tune import main
+    # main() turns on the persistent compile cache; a placed directory
+    # keeps this worker's JAX config (and the checkout) as they were
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax"))
     cache_dir = str(tmp_path / "cli")
     rc = main(["--kernel", "flash_attention", "--seq", "64", "--dim", "16",
                "--heads", "1", "--cache-dir", cache_dir, "--max-steps", "2",
@@ -323,8 +326,9 @@ def test_concurrent_writers_lose_no_entries(tmp_path):
 # -------------------------------------------------------- sweep farm
 
 def test_sweep_farm_two_workers_smoke(tmp_path):
-    """Tier-1 end-to-end: 2-process capture/measure over a shared cache,
-    simulator-first filtering, warm rerun fully served from artifacts."""
+    """Tier-1 end-to-end: 2-process capture, parent-side measurement
+    over a shared cache, simulator-first filtering, warm rerun fully
+    served from artifacts."""
     from repro.core.dse import run_sweep
     shapes = [{"S": 64, "D": 16}, {"S": 128, "D": 16}]
     cache = EvalCache(str(tmp_path / "sweep"))

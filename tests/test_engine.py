@@ -472,7 +472,7 @@ def test_engine_bit_identical_and_probed(tiny_model):
             for p, m in zip(prompts, max_new)]
     eng = InferenceEngine(model, params, EngineConfig(
         page_size=16, pool_pages=16, max_pages=2, buckets=(1, 2, 4),
-        probe=True, interpret=True))
+        probe=True))
     for p, m in zip(prompts, max_new):
         eng.submit(p, m)
     done = eng.run()
@@ -537,7 +537,7 @@ def test_engine_chunked_and_donated_bit_identical(tiny_model):
             for p, m in zip(prompts, max_new)]
     eng = InferenceEngine(model, params, EngineConfig(
         page_size=16, pool_pages=16, max_pages=2, buckets=(1, 2, 4),
-        probe=True, interpret=True, prefill_chunk_pages=1))
+        probe=True, prefill_chunk_pages=1))
     for p, m in zip(prompts, max_new):
         eng.submit(p, m)
     done = eng.run()
@@ -557,7 +557,7 @@ def test_engine_chunked_and_donated_bit_identical(tiny_model):
         warnings.simplefilter("ignore")
         eng = InferenceEngine(model, params, EngineConfig(
             page_size=16, pool_pages=16, max_pages=2, buckets=(1, 2, 4),
-            interpret=True, prefill_chunk_pages=1, donate=True))
+            prefill_chunk_pages=1, donate=True))
         eng.warmup()                   # donation rebinds the pool here
         for p, m in zip(prompts, max_new):
             eng.submit(p, m)
@@ -578,7 +578,7 @@ def test_engine_kernel_path_bit_identical(tiny_model):
             for p, m in zip(prompts, max_new)]
     eng = InferenceEngine(model, params, EngineConfig(
         page_size=16, pool_pages=16, max_pages=2, buckets=(1, 4),
-        use_kernel=True, pages_per_step=2, interpret=True))
+        use_kernel=True, pages_per_step=2))
     for p, m in zip(prompts, max_new):
         eng.submit(p, m)
     done = eng.run()
@@ -594,6 +594,7 @@ def test_paged_attention_kernel_matches_dense():
     einsum reference bit for bit, across pipelining depths."""
     import jax.numpy as jnp
     from repro.kernels.paged_attention import paged_attention
+    from repro.kernels.ref import paged_attention_ref
     B, KV, G, HD, PS, NP, POOL = 3, 2, 2, 8, 4, 4, 16
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(ks[0], (B, KV, G, HD), jnp.float32)
@@ -602,20 +603,7 @@ def test_paged_attention_kernel_matches_dense():
     pages = jax.random.permutation(
         ks[3], POOL)[:B * NP].reshape(B, NP).astype(jnp.int32)
     pos = jnp.array([0, 7, 15], jnp.int32)
-    s_max = PS * NP
-    kd = pk[pages].reshape(B, s_max, KV, HD)
-    vd = pv[pages].reshape(B, s_max, KV, HD)
-    qg = q[:, None]
-    s = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.bfloat16),
-                   kd.astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32) / np.sqrt(HD)
-    mask = jnp.arange(s_max)[None, :] <= pos[:, None]
-    s = jnp.where(mask[:, None, None, None, :], s, -jnp.inf)
-    p = jnp.exp(s - s.max(-1, keepdims=True))
-    ref = jnp.einsum("bkgqs,bskh->bkgqh",
-                     (p / p.sum(-1, keepdims=True)).astype(jnp.bfloat16),
-                     vd.astype(jnp.bfloat16),
-                     preferred_element_type=jnp.float32)[:, :, :, 0]
+    ref = paged_attention_ref(q, pk, pv, pages, pos)
     for pps in (1, 2, 4):
         out = paged_attention(q, pk, pv, pages, pos, pages_per_step=pps,
                               interpret=True)

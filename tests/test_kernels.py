@@ -136,3 +136,63 @@ def test_flash_gqa_adapter_matches_model_path():
                                v.transpose(0, 2, 1, 3),
                                causal=True).transpose(0, 2, 1, 3)
     assert _rel(o_pl, o_xla) < 5e-3   # model path uses bf16 dots
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_default_only_on_cpu(monkeypatch, backend, want):
+    """Interpret mode on the CPU, compiled kernels on the TPU, and an
+    error anywhere else: a run meant for the chip never lands in the
+    interpreter unnoticed."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret_default()
+    else:
+        assert ops._interpret_default() is want
+
+
+def _chip_smoke():
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kv_head_shifted(kernel):
+    """Reads kv head h + 1 where head h belongs."""
+    return lambda q, pk, pv, pages, pos: kernel(
+        q, jnp.roll(pk, 1, axis=2), jnp.roll(pv, 1, axis=2), pages, pos)
+
+
+def _pages_shifted(kernel):
+    """Walks each page-table row one page late."""
+    return lambda q, pk, pv, pages, pos: kernel(
+        q, pk, pv, jnp.roll(pages, 1, axis=1), pos)
+
+
+def _mask_page_late(kernel):
+    """Masks one page past the current position."""
+    return lambda q, pk, pv, pages, pos: kernel(q, pk, pv, pages, pos + 16)
+
+
+@pytest.mark.parametrize("fault", [None, _kv_head_shifted, _pages_shifted,
+                                   _mask_page_late])
+def test_chip_smoke_attention_bound_catches_faults(fault):
+    """The chip smoke run's kernel check at tinyllama's decode widths:
+    the kernel sits inside its bf16 rounding bound, and each planted
+    fault lands far outside it."""
+    from repro.configs.registry import get_config
+    from repro.kernels.paged_attention import paged_attention
+    smoke = _chip_smoke()
+    kernel = lambda *a: paged_attention(*a, interpret=True)  # noqa: E731
+    attend = kernel if fault is None else fault(kernel)
+    gap, limit = smoke.attention_gap(attend, get_config("tinyllama-1.1b"),
+                                     n_pages=10)
+    if fault is None:
+        assert gap <= limit
+    else:
+        assert gap > 4 * limit, (gap, limit)

@@ -171,7 +171,7 @@ def test_shard_oracle_resolves_axis_index():
     from repro.core.hierarchy import extract
     from repro.core.instrument import ProbeAssignment
     from repro.core.meshprobe import ShardOracle
-    from repro.distributed import compat
+    from repro.core.meshprobe import extend_axis_env
 
     def fn(x):
         i = jax.lax.axis_index("dev")
@@ -184,7 +184,7 @@ def test_shard_oracle_resolves_axis_index():
             x, n = jax.lax.while_loop(cond, body, (x, jnp.int32(0)))
         return jnp.sum(x), n
 
-    with compat.extend_axis_env({"dev": 4}):
+    with extend_axis_env({"dev": 4}):
         closed = jax.make_jaxpr(fn)(jnp.ones((4,)))
     h = extract(closed)
     asg = ProbeAssignment(paths=("dynamic",), depth=4, spill=(False,))
@@ -203,6 +203,7 @@ def test_shard_oracle_resolves_axis_index():
 def run_sub(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"            # forced host devices, never the chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=540)
@@ -285,7 +286,7 @@ with MeshProbeSession(mesh_probe(counted_step, mesh, (P("dev"), P()), P(),
     snap = s.snapshot()
     # zero retraces: the user function is traced ONCE for the whole
     # session, and the executable cache is steady from step 2 on (the
-    # 0.4.x C++ fastpath adds one signature entry without re-lowering)
+    # C++ fastpath may add one signature entry without re-lowering)
     steady = (sizes[0] is None or len(set(sizes[1:])) == 1)
     traces = fn_traces[0] if steady else -1
 sess_ok = (np.array_equal(snap.record.totals, K * rec.totals) and

@@ -124,3 +124,22 @@ def test_hlo_cost_trip_count_awareness():
     c1 = analyze(jax.jit(f1).lower(x, w).compile().as_text())
     ratio = c8["flops"] / max(c1["flops"], 1)
     assert 6.0 < ratio < 10.0, ratio
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache is
+    the fixed <repo>/.jax_cache."""
+    import os
+    from repro.launch import compile_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cc.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert cc.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -139,12 +139,12 @@ def test_trace_store_merge_and_staleness_key(tmp_path, captured):
 # --------------------------------------------------- collective context
 
 def test_collective_sites_reprice_with_mesh_context():
-    from repro.distributed import compat
+    from repro.core.meshprobe import extend_axis_env
 
     def fn(x):
         return jax.lax.psum(x * 2.0, "dev")
 
-    with compat.extend_axis_env({"dev": 8}):
+    with extend_axis_env({"dev": 8}):
         closed = jax.make_jaxpr(fn)(jnp.ones((4096,), jnp.float32))
     entry = ts.capture_closed(closed)
     assert len(entry.collectives) == 1

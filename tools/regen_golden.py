@@ -136,7 +136,7 @@ def run_engine_case() -> Dict[str, Any]:
     max_new = [5, 3, 4, 6]
     eng = InferenceEngine(model, params, EngineConfig(
         page_size=16, pool_pages=32, max_pages=4, buckets=(1, 2, 4),
-        probe=True, interpret=True))
+        probe=True))
     for p, m in zip(prompts, max_new):
         eng.submit(p, m)
     done = eng.run()
