@@ -48,6 +48,16 @@ from :mod:`repro.engine.step`):
   ``pool_k``/``pool_v`` to each step's outputs; the donated inputs are
   dead the moment the step is called and must never be re-read.
 
+- **Spans and counters.** Each scheduler round, admission, prefill,
+  decode dispatch, blocking device-to-host read, token emission, bus
+  publish and step program's first call runs under a profiler span
+  (``engine.*``, :mod:`repro.telemetry.spans`), so a profile attributes
+  the device's idle gaps to the host work under them. The same sites
+  keep cumulative host seconds in ``stats()`` (``rounds``, ``host_s``,
+  ``sync_s``, ``publish_s``, ``first_calls``, ``first_call_s``,
+  ``gc_s``), and each request carries host-clock stamps
+  (``t_submit``, ``t_admit``, ``t_first``, ``token_times``).
+
 Outputs are bit-identical to the unbatched reference serving path
 (asserted in tests/test_engine.py) — batching, paging, padding, and
 prefix sharing are all exact-arithmetic-preserving transformations.
@@ -55,6 +65,7 @@ prefix sharing are all exact-arithmetic-preserving transformations.
 from __future__ import annotations
 
 import math
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -68,8 +79,15 @@ from repro.engine.pagetable import (NULL_PAGE, PagePoolExhausted, PageTable,
 from repro.engine.step import (build_chunk_prefill, build_engine_prefill,
                                build_page_scatter, build_paged_decode,
                                donation_argnums, engine_compatible)
+from repro.telemetry.spans import gc_seconds, timed
 
 PHASES = ("prefill", "cache", "decode")
+
+
+def _size_tag(size) -> str:
+    """A step's size as one token: ``8`` or, for a chunk, ``8x2``."""
+    return str(size) if isinstance(size, int) \
+        else "x".join(str(s) for s in size)
 
 
 @dataclass
@@ -88,6 +106,12 @@ class Request:
     pos: int = -1                     # last cache position written
     last_tok: int = -1
     done: bool = False
+    # host perf_counter stamps: queued, admitted, first token, and each
+    # token's arrival on the host (one per out_tokens entry)
+    t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0
+    token_times: List[float] = field(default_factory=list)
 
     @property
     def prompt_len(self) -> int:
@@ -199,6 +223,15 @@ class InferenceEngine:
         self.evictions = 0                    # pages reclaimed from tree
         self.hol_blocked_steps = 0            # decode rounds displaced
         self.tokens_out = 0
+        # host-side counters (cumulative seconds; see counters())
+        self._called: set = set()             # (phase, size) called once
+        self.rounds = 0
+        self.host_s = 0.0
+        self.sync_s = 0.0
+        self.publish_s = 0.0
+        self.first_calls = 0
+        self.first_call_s = 0.0
+        self._gc0 = gc_seconds()
 
     # -- step registry ---------------------------------------------------
     def _build(self, phase: str, size):
@@ -216,12 +249,10 @@ class InferenceEngine:
                 use_kernel=c.use_kernel, pages_per_step=c.pages_per_step)
         if c.probe:
             from repro.core import ProbeConfig, ProbeSession
-            tag = size if isinstance(size, int) \
-                else "x".join(str(s) for s in size)
             return ProbeSession(fn, ProbeConfig(
                 targets=c.probe_targets, offload=1.0,
                 max_probes=c.probe_max_probes),
-                bus=self.bus, source=f"engine/{phase}x{tag}")
+                bus=self.bus, source=f"engine/{phase}x{_size_tag(size)}")
         dn = donation_argnums(phase) if self._donate else ()
         return jax.jit(fn, donate_argnums=dn)
 
@@ -231,8 +262,35 @@ class InferenceEngine:
             entry = self._steps[(phase, size)] = self._build(phase, size)
         return entry
 
-    def _invoke(self, entry, *args):
-        return entry.step(*args) if self.config.probe else entry(*args)
+    def _invoke(self, phase: str, size, *args):
+        """Call the ``(phase, size)`` step program. Its first call traces
+        it and compiles it or loads it from the cache: that call runs
+        under an ``engine.compile`` span and counts in ``first_calls``
+        and ``first_call_s``."""
+        entry = self._entry(phase, size)
+        call = entry.step if self.config.probe else entry
+        if (phase, size) in self._called:
+            return call(*args)
+        with timed("engine.compile", phase=phase, size=_size_tag(size)) as t:
+            out = call(*args)
+        self._called.add((phase, size))
+        self.first_calls += 1
+        self.first_call_s += t.s
+        return out
+
+    def _sync(self, phase: str, x) -> np.ndarray:
+        """Block on one device-to-host read (``engine.sync``)."""
+        with timed("engine.sync", phase=phase) as t:
+            out = np.asarray(x)
+        self.sync_s += t.s
+        return out
+
+    def _publish(self, topic: str, fn, *args, **kw):
+        """One call into the bus, which runs its subscribers' code on
+        this thread (``engine.publish``)."""
+        with timed("engine.publish", topic=topic) as t:
+            fn(*args, **kw)
+        self.publish_s += t.s
 
     def _chunk_shapes(self) -> List[Tuple[int, int]]:
         """Every (ctx_pages, chunk_pages) continuation shape the chunked
@@ -260,25 +318,22 @@ class InferenceEngine:
         c, ps = self.config, self.config.page_size
         for pp in range(1, c.max_pages + 1):
             _, k, v = self._invoke(
-                self._entry("prefill", pp), self.params,
+                "prefill", pp, self.params,
                 {"tokens": jnp.zeros((1, pp * ps), jnp.int32),
                  "last_idx": jnp.zeros((1,), jnp.int32)})
-            out = self._invoke(self._entry("cache", pp), self.pool_k,
-                               self.pool_v, k, v,
+            out = self._invoke("cache", pp, self.pool_k, self.pool_v, k, v,
                                jnp.zeros((pp,), jnp.int32))
             if self._donate:
                 self.pool_k, self.pool_v = out
         for (cs, n) in self._chunk_shapes():
             self._invoke(
-                self._entry("chunkpf", (cs, n)), self.params, self.pool_k,
-                self.pool_v,
+                "chunkpf", (cs, n), self.params, self.pool_k, self.pool_v,
                 {"tokens": jnp.zeros((1, n * ps), jnp.int32),
                  "ctx_pages": jnp.zeros((cs,), jnp.int32),
                  "last_idx": jnp.zeros((1,), jnp.int32)})
         for b in c.buckets:
             out = self._invoke(
-                self._entry("decode", b), self.params, self.pool_k,
-                self.pool_v,
+                "decode", b, self.params, self.pool_k, self.pool_v,
                 {"tokens": jnp.zeros((b, 1), jnp.int32),
                  "pos": jnp.zeros((b,), jnp.int32),
                  "pages": jnp.zeros((b, c.max_pages), jnp.int32)})
@@ -287,21 +342,20 @@ class InferenceEngine:
 
     def _step(self, phase: str, size, *args):
         """Run one step, return (outputs, model-clock cycle delta)."""
-        entry = self._entry(phase, size)
         if self.config.probe:
+            entry = self._entry(phase, size)
             c0 = entry.clock()
-            out = entry.step(*args)
+            out = self._invoke(phase, size, *args)
             delta = entry.clock() - c0
         else:
-            out = entry(*args)
+            out = self._invoke(phase, size, *args)
             delta = 0
         st = self.phase_stats.setdefault(phase, {"steps": 0, "cycles": 0})
         st["steps"] += 1
         st["cycles"] += delta
         if self.bus is not None:
-            self.bus.publish_phase(phase, cycles=delta,
-                                   batch=size if phase == "decode"
-                                   else None)
+            self._publish(phase, self.bus.publish_phase, phase, cycles=delta,
+                          batch=size if phase == "decode" else None)
         return out, delta
 
     def retraces(self) -> int:
@@ -327,7 +381,8 @@ class InferenceEngine:
             raise ValueError(
                 f"request needs {self._pages_needed(len(prompt), max_new)} "
                 f"pages; page table holds {self.config.max_pages}")
-        r = Request(rid=self._next_rid, prompt=prompt, max_new=max_new)
+        r = Request(rid=self._next_rid, prompt=prompt, max_new=max_new,
+                    t_submit=time.perf_counter())
         self._next_rid += 1
         self._waiting.append(r)
         return r.rid
@@ -374,6 +429,7 @@ class InferenceEngine:
         fresh = self.table.alloc(n_pages - len(shared))
         r.pages = shared + fresh
         r.shared_pages = len(shared)
+        r.t_admit = time.perf_counter()
         self._start_prefill(r, page_tokens)
         return True
 
@@ -382,7 +438,9 @@ class InferenceEngine:
         K = self.config.prefill_chunk_pages
         pp = math.ceil(len(r.prompt) / self.config.page_size)
         if not K or pp <= K:
-            self._prefill(r, page_tokens)
+            with timed("engine.prefill", rid=r.rid, prompt_len=len(r.prompt),
+                       pages=pp, queue_ms=1e3 * (r.t_admit - r.t_submit)):
+                self._prefill(r, page_tokens)
             return
         # chunks start at multiples of K; fully prefix-shared leading
         # chunks are skipped (their pages already hold these exact KV
@@ -415,7 +473,9 @@ class InferenceEngine:
         self._emit_first_token(r, logits)
 
     def _emit_first_token(self, r: Request, logits):
-        tok = int(jnp.argmax(logits, axis=-1)[0])
+        tok = int(self._sync("prefill", jnp.argmax(logits, axis=-1)[0]))
+        r.t_first = time.perf_counter()
+        r.token_times.append(r.t_first)
         r.out_tokens.append(tok)
         self.tokens_out += 1
         r.last_tok = tok
@@ -432,40 +492,42 @@ class InferenceEngine:
         r, ps = job.req, c.page_size
         P, pp, cs = len(r.prompt), job.pp, job.next_page
         n = min(c.prefill_chunk_pages, pp - cs)
-        final = cs + n >= pp
-        toks = np.zeros((1, n * ps), np.int32)
-        seg = r.prompt[cs * ps:min(P, (cs + n) * ps)]
-        toks[0, :len(seg)] = seg
-        li = (P - 1 - cs * ps) if final else (n * ps - 1)
-        batch = {"tokens": jnp.asarray(toks),
-                 "last_idx": jnp.array([li], jnp.int32)}
-        if cs == 0:
-            (logits, k, v), d = self._step("prefill", n, self.params,
-                                           batch)
-        else:
-            batch["ctx_pages"] = jnp.array(r.pages[:cs], jnp.int32)
-            (logits, k, v), d = self._step(
-                "chunkpf", (cs, n), self.params, self.pool_k, self.pool_v,
-                batch)
-        r.phase_cycles["prefill"] += d
-        ids = jnp.array(r.pages[cs:cs + n], jnp.int32)
-        (self.pool_k, self.pool_v), dc = self._step(
-            "cache", n, self.pool_k, self.pool_v, k, v, ids)
-        r.phase_cycles["cache"] += dc
-        cst = self.chunk_stats.setdefault((cs, n),
-                                          {"steps": 0, "cycles": 0})
-        cst["steps"] += 1
-        cst["cycles"] += d + dc
-        job.next_page = cs + n
-        # publish fully-written prompt pages incrementally so requests
-        # arriving mid-prefill can already share the finished chunks
-        if self.tree is not None and job.page_tokens:
-            done_pages = min(cs + n, len(job.page_tokens))
-            self.tree.insert(job.page_tokens[:done_pages],
-                             r.pages[:done_pages])
-        if final:
-            self._prefilling.popleft()
-            self._emit_first_token(r, logits)
+        with timed("engine.chunk", rid=r.rid, prompt_len=P, ctx_pages=cs,
+                   chunk_pages=n, queue_ms=1e3 * (r.t_admit - r.t_submit)):
+            final = cs + n >= pp
+            toks = np.zeros((1, n * ps), np.int32)
+            seg = r.prompt[cs * ps:min(P, (cs + n) * ps)]
+            toks[0, :len(seg)] = seg
+            li = (P - 1 - cs * ps) if final else (n * ps - 1)
+            batch = {"tokens": jnp.asarray(toks),
+                     "last_idx": jnp.array([li], jnp.int32)}
+            if cs == 0:
+                (logits, k, v), d = self._step("prefill", n, self.params,
+                                               batch)
+            else:
+                batch["ctx_pages"] = jnp.array(r.pages[:cs], jnp.int32)
+                (logits, k, v), d = self._step(
+                    "chunkpf", (cs, n), self.params, self.pool_k, self.pool_v,
+                    batch)
+            r.phase_cycles["prefill"] += d
+            ids = jnp.array(r.pages[cs:cs + n], jnp.int32)
+            (self.pool_k, self.pool_v), dc = self._step(
+                "cache", n, self.pool_k, self.pool_v, k, v, ids)
+            r.phase_cycles["cache"] += dc
+            cst = self.chunk_stats.setdefault((cs, n),
+                                              {"steps": 0, "cycles": 0})
+            cst["steps"] += 1
+            cst["cycles"] += d + dc
+            job.next_page = cs + n
+            # publish fully-written prompt pages incrementally so requests
+            # arriving mid-prefill can already share the finished chunks
+            if self.tree is not None and job.page_tokens:
+                done_pages = min(cs + n, len(job.page_tokens))
+                self.tree.insert(job.page_tokens[:done_pages],
+                                 r.pages[:done_pages])
+            if final:
+                self._prefilling.popleft()
+                self._emit_first_token(r, logits)
 
     def _complete(self, r: Request):
         for p in r.pages:
@@ -474,73 +536,99 @@ class InferenceEngine:
         r.done = True
         self._finished.append(r)
         if self.bus is not None:
-            self.bus.publish_request({
+            self._publish("request", self.bus.publish_request, {
                 "rid": r.rid, "prompt_len": r.prompt_len,
                 "tokens": len(r.out_tokens),
                 "shared_pages": r.shared_pages,
                 "decode_batches": list(r.decode_batches),
-                "phase_cycles": dict(r.phase_cycles)})
+                "phase_cycles": dict(r.phase_cycles),
+                "queue_ms": 1e3 * (r.t_admit - r.t_submit),
+                "first_token_ms": 1e3 * (r.t_first - r.t_submit)})
 
     def _admit(self):
-        while self._waiting and (len(self._active) + len(self._prefilling)
-                                 < self.config.buckets[-1]):
-            if not self._try_admit(self._waiting[0]):
-                break                   # FCFS: the head blocks the line
-            self._waiting.popleft()
+        lanes = self.config.buckets[-1]
+        with timed("engine.admit") as t:
+            n = 0
+            while self._waiting and \
+                    len(self._active) + len(self._prefilling) < lanes:
+                if not self._try_admit(self._waiting[0]):
+                    break               # FCFS: the head blocks the line
+                self._waiting.popleft()
+                n += 1
+            t.set(admitted=n)
 
     def _decode_round(self):
         c = self.config
         sel = self._active[:c.buckets[-1]]
         bucket = next(b for b in c.buckets if b >= len(sel))
-        self.bucket_hist[bucket] = self.bucket_hist.get(bucket, 0) + 1
-        pages = np.zeros((bucket, c.max_pages), np.int32)
-        pos = np.zeros(bucket, np.int32)
-        toks = np.zeros((bucket, 1), np.int32)
-        for i, r in enumerate(sel):
-            pages[i, :len(r.pages)] = r.pages
-            pos[i] = r.pos + 1
-            toks[i, 0] = r.last_tok
-        (_, self.pool_k, self.pool_v, next_tok), d = self._step(
-            "decode", bucket, self.params, self.pool_k, self.pool_v,
-            {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos),
-             "pages": jnp.asarray(pages)})
-        next_tok = np.asarray(next_tok)
-        finished = []
-        for i, r in enumerate(sel):
-            r.pos += 1
-            tok = int(next_tok[i])
-            r.out_tokens.append(tok)
-            self.tokens_out += 1
-            r.last_tok = tok
-            r.decode_batches.append(bucket)
-            r.phase_cycles["decode"] += d
-            if len(r.out_tokens) >= r.max_new:
-                finished.append(r)
-        for r in finished:
-            self._active.remove(r)
-            self._complete(r)
+        with timed("engine.decode", bucket=bucket, lanes=len(sel)):
+            self.bucket_hist[bucket] = self.bucket_hist.get(bucket, 0) + 1
+            pages = np.zeros((bucket, c.max_pages), np.int32)
+            pos = np.zeros(bucket, np.int32)
+            toks = np.zeros((bucket, 1), np.int32)
+            for i, r in enumerate(sel):
+                pages[i, :len(r.pages)] = r.pages
+                pos[i] = r.pos + 1
+                toks[i, 0] = r.last_tok
+            (_, self.pool_k, self.pool_v, next_tok), d = self._step(
+                "decode", bucket, self.params, self.pool_k, self.pool_v,
+                {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos),
+                 "pages": jnp.asarray(pages)})
+            next_tok = self._sync("decode", next_tok)
+            now = time.perf_counter()
+            with timed("engine.emit") as t:
+                finished = []
+                for i, r in enumerate(sel):
+                    r.pos += 1
+                    tok = int(next_tok[i])
+                    r.token_times.append(now)
+                    r.out_tokens.append(tok)
+                    self.tokens_out += 1
+                    r.last_tok = tok
+                    r.decode_batches.append(bucket)
+                    r.phase_cycles["decode"] += d
+                    if len(r.out_tokens) >= r.max_new:
+                        finished.append(r)
+                t.set(finished=len(finished))
+                for r in finished:
+                    self._active.remove(r)
+                    self._complete(r)
 
     def run(self) -> List[Request]:
         """Serve until every submitted request has finished; returns the
-        requests completed by this call, in submission order."""
+        requests completed by this call, in submission order. Each
+        iteration is one scheduler round (``engine.round``, whose
+        arguments are the queue and ``counters()`` at its start)."""
         start = len(self._finished)
         while self._waiting or self._active or self._prefilling:
-            self._admit()
-            progressed = False
-            if self._prefilling:         # one chunk quantum per round,
-                self._chunk_step()       # interleaved with decode below
-                progressed = True
-            if self._active:
-                self._decode_round()
-                progressed = True
-            if not progressed and self._waiting:
-                # head unadmittable with an otherwise idle engine
-                r = self._waiting[0]
-                raise PagePoolExhausted(
-                    f"request {r.rid} needs "
-                    f"{self._pages_needed(len(r.prompt), r.max_new)} pages "
-                    f"with only {self.table.free_pages} free")
+            sync0, pub0 = self.sync_s, self.publish_s
+            with timed("engine.round", active=len(self._active),
+                       waiting=len(self._waiting), **self.counters()) as t:
+                self._round()
+            self.rounds += 1
+            self.host_s += t.s - (self.sync_s - sync0) \
+                - (self.publish_s - pub0)
+            if self.bus is not None:
+                self._publish("counters", self.bus.publish_counters,
+                              self.counters())
         return sorted(self._finished[start:], key=lambda r: r.rid)
+
+    def _round(self):
+        self._admit()
+        progressed = False
+        if self._prefilling:             # one chunk quantum per round,
+            self._chunk_step()           # interleaved with decode below
+            progressed = True
+        if self._active:
+            self._decode_round()
+            progressed = True
+        if not progressed and self._waiting:
+            # head unadmittable with an otherwise idle engine
+            r = self._waiting[0]
+            raise PagePoolExhausted(
+                f"request {r.rid} needs "
+                f"{self._pages_needed(len(r.prompt), r.max_new)} pages "
+                f"with only {self.table.free_pages} free")
 
     def reap(self) -> List[Request]:
         """Pop every finished request. Long-lived servers call this per
@@ -567,6 +655,19 @@ class InferenceEngine:
             for entry in self._steps.values():
                 entry.close()
 
+    def counters(self) -> Dict[str, Any]:
+        """Cumulative host-side counters: scheduler rounds; ``host_s``,
+        the rounds' time less ``sync_s`` (blocking device-to-host reads)
+        and ``publish_s`` (calls into the bus); ``first_calls`` and
+        ``first_call_s``, the step programs' first calls (trace, compile
+        or cache load, dispatch); ``gc_s``, the process's collector time
+        since this engine was built."""
+        return {"rounds": self.rounds, "host_s": self.host_s,
+                "sync_s": self.sync_s, "publish_s": self.publish_s,
+                "first_calls": self.first_calls,
+                "first_call_s": self.first_call_s,
+                "gc_s": gc_seconds() - self._gc0}
+
     def stats(self) -> Dict[str, Any]:
         hits = self.tree.hits if self.tree else 0
         misses = self.tree.misses if self.tree else 0
@@ -584,6 +685,7 @@ class InferenceEngine:
             "evictions": self.evictions,
             "hol_blocked_steps": self.hol_blocked_steps,
             "tokens_out": self.tokens_out,
+            **self.counters(),
         }
 
     def phase_table(self) -> str:

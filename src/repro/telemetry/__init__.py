@@ -5,7 +5,9 @@ aggregates to; ``server`` exposes it over HTTP (``/status``,
 ``/probes``, ``/mesh/skew``, ``/engine/phases``, ``/alerts``,
 ``/metrics``); ``sentinel`` watches the window stream for online drift
 (p99 regressions, histogram shifts, straggler devices) and can trigger
-a background DSE re-tune.  See docs/telemetry.md.
+a background DSE re-tune; ``spans`` puts the serving engine's host work
+under profiler spans and keeps its host-time counters.  See
+docs/telemetry.md.
 """
 from repro.telemetry.bus import (ProbeStream, TelemetryBus, WindowFrame,
                                  hist_quantile)

@@ -20,8 +20,9 @@ pub/sub abstraction all three publish to:
   count/total/histogram deltas to every ``"window"`` subscriber.  The
   :class:`~repro.telemetry.sentinel.DriftSentinel` is such a
   subscriber.
-- **engine topics** — per-phase step/cycle totals and bounded
-  per-request bills (``publish_phase`` / ``publish_request``).
+- **engine topics** — per-phase step/cycle totals, bounded
+  per-request bills (``publish_phase`` / ``publish_request``) and the
+  engine's latest host counters (``publish_counters``).
 - **alerts** — structured :class:`~repro.telemetry.sentinel.DriftEvent`
   records (``publish_alert``), kept in a bounded ring and surfaced on
   the status server's ``/alerts`` endpoint.
@@ -189,6 +190,7 @@ class _EngineStats:
     buckets: Dict[int, int] = field(default_factory=dict)
     requests_done: int = 0
     recent: deque = field(default_factory=lambda: deque(maxlen=64))
+    counters: Dict[str, Any] = field(default_factory=dict)
 
 
 class TelemetryBus:
@@ -264,6 +266,12 @@ class TelemetryBus:
             self.engine.recent.append(dict(info))
         for fn in self._snapshot_subs("request"):
             fn(info)
+
+    def publish_counters(self, counters: Dict[str, Any]):
+        """Replace the engine's cumulative host counters (rounds, host,
+        sync, publish, first-call and collector seconds)."""
+        with self._lock:
+            self.engine.counters = dict(counters)
 
     # -- alerts ----------------------------------------------------------
     def publish_alert(self, event: Any):

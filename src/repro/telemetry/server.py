@@ -15,7 +15,8 @@ endpoint          content
                   ``StreamAggregator`` values
 ``/mesh/skew``    device-major streams: per-probe skew, per-device
                   totals, worst (device, probe) cell
-``/engine/phases``  per-phase step/cycle bills + recent request bills
+``/engine/phases``  per-phase step/cycle bills, recent request bills,
+                  the engine's host counters
 ``/alerts``       the sentinel's fired ``DriftEvent`` ring
 ``/metrics``      Prometheus-style text exposition of the same numbers
 ================  =====================================================
@@ -81,6 +82,7 @@ def _engine_doc(bus: TelemetryBus) -> Dict[str, Any]:
             "buckets": {str(k): v for k, v in bus.engine.buckets.items()},
             "requests_done": bus.engine.requests_done,
             "recent_requests": list(bus.engine.recent),
+            "counters": dict(bus.engine.counters),
         }
 
 
@@ -88,6 +90,17 @@ def _alerts_doc(bus: TelemetryBus) -> Dict[str, Any]:
     events = [e.to_dict() if hasattr(e, "to_dict") else dict(e)
               for e in bus.alerts()]
     return {"total": bus.alerts_total, "events": events}
+
+
+# the engine's host counters exported on /metrics (InferenceEngine.counters)
+ENGINE_COUNTERS = (
+    ("rounds", "engine scheduler rounds"),
+    ("host_s", "seconds of engine rounds less sync and publish"),
+    ("sync_s", "seconds blocked on device-to-host reads"),
+    ("publish_s", "seconds in bus calls (subscribers included)"),
+    ("first_call_s", "seconds in step programs' first calls"),
+    ("gc_s", "seconds in Python's collector since the engine was built"),
+)
 
 
 def _esc(v: Any) -> str:
@@ -136,6 +149,10 @@ def render_metrics(bus: TelemetryBus) -> str:
     metric("repro_engine_requests_total",
            "finished engine requests", "counter",
            [({}, eng["requests_done"])])
+    for key, help_ in ENGINE_COUNTERS:
+        if key in eng["counters"]:
+            metric(f"repro_engine_{key}_total", help_, "counter",
+                   [({}, eng["counters"][key])])
     metric("repro_alerts_total",
            "drift events fired by the sentinel", "counter",
            [({}, bus.alerts_total)])
