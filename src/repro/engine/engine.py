@@ -41,6 +41,10 @@ from :mod:`repro.engine.step`):
   :class:`PagePoolExhausted` is reachable only when live requests alone
   exceed the pool. ``evict_policy="clear"`` keeps the legacy
   all-or-nothing behavior for A/B benchmarking.
+- **Lane-dense pool.** ``pool_k``/``pool_v`` are
+  ``(num_layers, pool_pages, page_size, kv_heads*head_dim)``; each step
+  family reshapes at its edges, and decode writes only the rows it
+  changes (:func:`repro.engine.step.build_paged_decode`).
 - **Donated pool buffers.** Off probe mode, steps that return an
   updated pool (cache scatter, decode) are jitted with
   ``donate_argnums`` so the paged KV pool updates in place instead of
@@ -156,6 +160,10 @@ class InferenceEngine:
         done = eng.run()          # list of finished Requests, rid order
         print(eng.phase_table()); print(eng.request_table(done))
         eng.drain()               # release prefix-cache pages
+
+    ``pool_k``/``pool_v`` hold the paged KV cache, each
+    ``(num_layers, pool_pages, page_size, kv_heads*head_dim)`` in the
+    model's ``kv_cache_dtype``; page ``NULL_PAGE`` is never handed out.
     """
 
     def __init__(self, model, params, config: EngineConfig = EngineConfig(),
@@ -202,8 +210,8 @@ class InferenceEngine:
         self._donate = (config.donate if config.donate is not None
                         else (not config.probe
                               and jax.default_backend() != "cpu"))
-        kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-        shape = (cfg.num_layers, config.pool_pages, config.page_size, kv, hd)
+        shape = (cfg.num_layers, config.pool_pages, config.page_size,
+                 cfg.num_kv_heads * cfg.resolved_head_dim)
         kvd = jnp.dtype(cfg.kv_cache_dtype)
         self.pool_k = jnp.zeros(shape, kvd)
         self.pool_v = jnp.zeros(shape, kvd)

@@ -1,10 +1,11 @@
 """Paged KV-cache bookkeeping: refcounted page table + prefix tree.
 
 Host-side metadata for the device-resident page pool. The pool itself
-is a pair of ``(num_pages, page_size, kv_heads, head_dim)`` arrays held
-by the engine; this module only tracks which pages are free, how many
-requests reference each page, and which fully-written prompt pages can
-be shared between requests with a common prompt prefix.
+is a pair of ``(num_layers, num_pages, page_size, kv_heads*head_dim)``
+arrays held by the engine; this module only tracks which pages are
+free, how many requests reference each page, and which fully-written
+prompt pages can be shared between requests with a common prompt
+prefix.
 
 Page 0 is the **null page**: permanently reserved, never handed out.
 Padded rows of a decode bucket point their whole page-table row at it,

@@ -25,6 +25,13 @@ retraces (asserted in tests/test_engine.py). Three step families:
   rows, and every _flash_row op is row-independent, so chunked prefill
   is bit-identical to the equivalent whole-prompt prefill step.
 
+The pool is lane-dense: ``(num_layers, pool_pages, page_size,
+kv_heads*head_dim)``, whose minor dimensions tile without padding on
+the TPU, so no step relays it. Prefill and chunkpf return their page
+blocks in the same layout, and each family reshapes to ``(kv, hd)``
+only what it gathers. The XLA decode reads the pool in place and writes
+only the rows it changes, once per step, after the layer scan.
+
 Padded lanes of a decode bucket run token 0 at position 0 against the
 null page; every dummy lane writes identical values to the same slot,
 so the pool stays deterministic and no real page is touched.
@@ -32,7 +39,7 @@ so the pool stays deterministic and no real page is touched.
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict, Tuple
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +78,7 @@ def build_engine_prefill(model, n_pages: int, page_size: int) -> Callable:
 
     fn(params, batch) with batch = {"tokens": (1, n_pages*page_size),
     "last_idx": (1,)} -> (logits (1, V) at last_idx, k, v) where k/v are
-    (L, n_pages, page_size, kv_heads, head_dim) page-major cache blocks.
+    (L, n_pages, page_size, kv_heads*head_dim) lane-dense page blocks.
     """
     cfg = model.cfg
     seq = n_pages * page_size
@@ -93,9 +100,9 @@ def build_engine_prefill(model, n_pages: int, page_size: int) -> Callable:
                 preferred_element_type=jnp.float32)
             logits = model._mask_pad(logits)
         L = cache["k"].shape[0]
-        kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-        k = cache["k"].reshape(L, n_pages, page_size, kv, hd)
-        v = cache["v"].reshape(L, n_pages, page_size, kv, hd)
+        width = cfg.num_kv_heads * cfg.resolved_head_dim
+        k = cache["k"].reshape(L, n_pages, page_size, width)
+        v = cache["v"].reshape(L, n_pages, page_size, width)
         return logits, k, v
 
     return prefill
@@ -105,7 +112,9 @@ def build_page_scatter(n_pages: int) -> Callable:
     """Cache-management step: write ``n_pages`` prefilled page blocks
     into the pool at the request's page-table entries.
 
-    fn(pool_k, pool_v, k, v, page_ids (n_pages,)) -> (pool_k, pool_v).
+    fn(pool_k, pool_v, k, v, page_ids (n_pages,)) -> (pool_k, pool_v),
+    pools ``(L, P, page_size, kv*hd)`` and blocks
+    ``(L, n_pages, page_size, kv*hd)``: whole pages are set in place.
     Re-writing a prefix-shared page stores bit-identical values (same
     token prefix -> same KV rows), so sharing never perturbs readers.
     """
@@ -127,8 +136,9 @@ def build_chunk_prefill(model, ctx_pages: int, chunk_pages: int,
     fn(params, pool_k, pool_v, batch) with batch = {"tokens":
     (1, chunk_pages*page_size), "ctx_pages": (ctx_pages,) int32,
     "last_idx": (1,)} -> (logits (1, V) at last_idx *within the chunk*,
-    k, v) where k/v are (L, chunk_pages, page_size, kv, hd) page-major
-    cache blocks for the chunk's own rows.
+    k, v) where k/v are (L, chunk_pages, page_size, kv*hd) lane-dense
+    page blocks for the chunk's own rows. Each layer gathers its context
+    pages straight from the pool's ``(L*P, page_size, kv*hd)`` view.
 
     Bit-identity with whole-prompt prefill is structural: the flash
     blocks replay ``_row_plan(ctx+chunk, attn_chunk, attn_chunk)`` — the
@@ -164,22 +174,23 @@ def build_chunk_prefill(model, ctx_pages: int, chunk_pages: int,
         positions = jnp.broadcast_to(
             jnp.arange(ctx_len, S, dtype=jnp.int32)[None], (1, Sq))
         ctx_ids = batch["ctx_pages"]
+        L, P = pool_k.shape[:2]
+        page_k = pool_k.reshape(L * P, page_size, kv * hd)
+        page_v = pool_v.reshape(L * P, page_size, kv * hd)
 
         def body(carry, inp):
             h, = carry
             lp, li = inp
             with jax.named_scope("layer"):
-                kp = jax.lax.dynamic_index_in_dim(pool_k, li, 0,
-                                                  keepdims=False)
-                vp = jax.lax.dynamic_index_in_dim(pool_v, li, 0,
-                                                  keepdims=False)
                 with jax.named_scope("attn"):
                     qn = rmsnorm(h, lp["ln1"], cfg.norm_eps)
                     q, k_new, v_new = _project_qkv(lp["attn"], qn, cfg,
                                                    positions)
                     with jax.named_scope("ctx_gather"):
-                        kc = kp[ctx_ids].reshape(1, ctx_len, kv, hd)
-                        vc = vp[ctx_ids].reshape(1, ctx_len, kv, hd)
+                        kc = page_k[li * P + ctx_ids].reshape(
+                            1, ctx_len, kv, hd)
+                        vc = page_v[li * P + ctx_ids].reshape(
+                            1, ctx_len, kv, hd)
                         k_full = jnp.concatenate(
                             [kc.astype(cd), k_new], axis=1)
                         v_full = jnp.concatenate(
@@ -232,75 +243,43 @@ def build_chunk_prefill(model, ctx_pages: int, chunk_pages: int,
                 model._unembed_weight(p).astype(last.dtype),
                 preferred_element_type=jnp.float32)
             logits = model._mask_pad(logits)
-        L = cfg.num_layers
-        k = ks[:, 0].reshape(L, chunk_pages, page_size, kv, hd)
-        v = vs[:, 0].reshape(L, chunk_pages, page_size, kv, hd)
+        k = ks[:, 0].reshape(L, chunk_pages, page_size, kv * hd)
+        v = vs[:, 0].reshape(L, chunk_pages, page_size, kv * hd)
         return logits, k, v
 
     return chunkpf
 
 
-def _paged_attn_xla(lp, x, kp, vp, pages, pos, cfg, s_max: int,
-                    page_size: int):
-    """Dense-gather paged attend: ``attn_decode`` with vector positions
-    and a page-table cache — operation-for-operation the same math."""
-    positions = pos[:, None]
-    q, k_new, v_new = _project_qkv(lp, x, cfg, positions)
+def _decode_qkv(lp, x, pos, cfg):
+    """The decode token's grouped queries ``(B, 1, kv, g, hd)`` and its
+    new K/V rows ``(B, kv, hd)``, at vector positions ``pos``."""
+    q, k_new, v_new = _project_qkv(lp, x, cfg, pos[:, None])
     B = x.shape[0]
-    H, Hp = cfg.num_heads, q.shape[2]
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    if Hp != H:
-        q = q[:, :, :H]
-    qg = q.reshape(B, 1, kv, cfg.q_per_kv, hd)
-    with jax.named_scope("cache_update"):
-        pidx = jnp.take_along_axis(pages, (pos // page_size)[:, None],
-                                   axis=1)[:, 0]
-        slot = pos % page_size
-        kp = kp.at[pidx, slot].set(k_new[:, 0].astype(kp.dtype))
-        vp = vp.at[pidx, slot].set(v_new[:, 0].astype(vp.dtype))
-    with jax.named_scope("attend"):
-        scale = 1.0 / math.sqrt(hd)
-        kd = kp[pages].reshape(B, s_max, kv, hd)
-        vd = vp[pages].reshape(B, s_max, kv, hd)
-        s = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.bfloat16),
-                       kd.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32) * scale
-        mask = jnp.arange(s_max)[None, :] <= pos[:, None]
-        s = jnp.where(mask[:, None, None, None, :], s, -jnp.inf)
-        m = s.max(axis=-1, keepdims=True)
-        pr = jnp.exp(s - m)
-        l = pr.sum(axis=-1, keepdims=True)
-        o = jnp.einsum("bkgqs,bskh->bkgqh", (pr / l).astype(jnp.bfloat16),
-                       vd.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-        o = o[:, :, :, 0]                               # (B, kv, g, hd)
-    return o, kp, vp
+    if q.shape[2] != cfg.num_heads:
+        q = q[:, :, :cfg.num_heads]
+    qg = q.reshape(B, 1, cfg.num_kv_heads, cfg.q_per_kv,
+                   cfg.resolved_head_dim)
+    return qg, k_new[:, 0], v_new[:, 0]
 
 
-def _paged_attn_kernel(lp, x, kp, vp, pages, pos, cfg, s_max: int,
-                       page_size: int, pages_per_step: int):
-    """Pallas paged-attention attend (bit-identical to the XLA path),
-    interpreted on the CPU and compiled on the TPU."""
-    from repro.kernels.ops import _interpret_default
-    from repro.kernels.paged_attention import paged_attention
-    positions = pos[:, None]
-    q, k_new, v_new = _project_qkv(lp, x, cfg, positions)
-    B = x.shape[0]
-    H, Hp = cfg.num_heads, q.shape[2]
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    if Hp != H:
-        q = q[:, :, :H]
-    qg = q.reshape(B, 1, kv, cfg.q_per_kv, hd)
-    with jax.named_scope("cache_update"):
-        pidx = jnp.take_along_axis(pages, (pos // page_size)[:, None],
-                                   axis=1)[:, 0]
-        slot = pos % page_size
-        kp = kp.at[pidx, slot].set(k_new[:, 0].astype(kp.dtype))
-        vp = vp.at[pidx, slot].set(v_new[:, 0].astype(vp.dtype))
-    o = paged_attention(qg[:, 0], kp, vp, pages, pos,
-                        pages_per_step=pages_per_step,
-                        interpret=_interpret_default())
-    return o, kp, vp
+def _attend_dense(qg, kd, vd, pos):
+    """``attn_decode``'s math over a dense ``(B, s_max, kv, hd)`` gather
+    of each lane's pages: the same einsum shapes, the same global
+    softmax, vector positions instead of a shared scalar."""
+    s_max, hd = kd.shape[1], kd.shape[-1]
+    scale = 1.0 / math.sqrt(hd)
+    s = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.bfloat16),
+                   kd.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(s_max)[None, :] <= pos[:, None]
+    s = jnp.where(mask[:, None, None, None, :], s, -jnp.inf)
+    m = s.max(axis=-1, keepdims=True)
+    pr = jnp.exp(s - m)
+    l = pr.sum(axis=-1, keepdims=True)
+    o = jnp.einsum("bkgqs,bskh->bkgqh", (pr / l).astype(jnp.bfloat16),
+                   vd.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    return o[:, :, :, 0]                                # (B, kv, g, hd)
 
 
 def build_paged_decode(model, batch_size: int, n_pages: int,
@@ -310,17 +289,121 @@ def build_paged_decode(model, batch_size: int, n_pages: int,
 
     fn(params, pool_k, pool_v, batch) with batch = {"tokens": (B, 1),
     "pos": (B,), "pages": (B, n_pages)} ->
-    (logits (B, V), pool_k, pool_v, next_tokens (B,)).
+    (logits (B, V), pool_k, pool_v, next_tokens (B,)); the pools are
+    lane-dense ``(L, P, page_size, kv*hd)``.
+
+    The XLA path (``use_kernel=False``) reads the pool in place: each
+    layer gathers its lanes' pages straight from the pool and puts the
+    new token's K/V into that gathered copy at ``pos``. The L x B new
+    rows are written once, after the layer scan, by one row scatter into
+    the pool's ``(L*P*page_size, kv*hd)`` view, so only the written rows
+    move. The kernel path writes each layer's rows inside the scan, on a
+    ``(P, page_size, kv, hd)`` view of the layer, because the kernel
+    reads them from the pool.
     """
     cfg = model.cfg
     s_max = n_pages * page_size
+    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
 
-    def attend(lp, x, kp, vp, pages, pos):
-        if use_kernel:
-            return _paged_attn_kernel(lp, x, kp, vp, pages, pos, cfg,
-                                      s_max, page_size, pages_per_step)
-        return _paged_attn_xla(lp, x, kp, vp, pages, pos, cfg, s_max,
-                               page_size)
+    def out_proj(lp, h, o):
+        with jax.named_scope("out_proj"):
+            B = h.shape[0]
+            H = cfg.num_heads
+            Hp = lp["wo"].shape[0]
+            ow = o[:, None].reshape(B, 1, H, hd).astype(h.dtype)
+            if Hp != H:
+                ow = jnp.pad(ow, [(0, 0), (0, 0), (0, Hp - H), (0, 0)])
+            return jnp.einsum("bsnh,nhd->bsd", ow, lp["wo"])
+
+    def ffn(lp, h):
+        """The residual MLP or MoE block."""
+        if cfg.moe is not None:
+            with jax.named_scope("moe"):
+                mo, _ = moe_mod.moe_apply(
+                    lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
+        else:
+            with jax.named_scope("mlp"):
+                mo = mlp_apply(lp["mlp"],
+                               rmsnorm(h, lp["ln2"], cfg.norm_eps))
+        return h + mo
+
+    def layers_xla(layers, x, pool_k, pool_v, pages, pos, pidx, slot):
+        L, P = pool_k.shape[:2]
+        B = x.shape[0]
+        page_k = pool_k.reshape(L * P, page_size, kv * hd)
+        page_v = pool_v.reshape(L * P, page_size, kv * hd)
+        at_pos = (jnp.arange(s_max)[None, :] == pos[:, None])[..., None,
+                                                              None]
+
+        def body(h, inp):
+            lp, li = inp
+            with jax.named_scope("layer"):
+                with jax.named_scope("attn"):
+                    qg, k_new, v_new = _decode_qkv(
+                        lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps),
+                        pos, cfg)
+                    k_new = k_new.astype(pool_k.dtype)
+                    v_new = v_new.astype(pool_v.dtype)
+                    with jax.named_scope("attend"):
+                        ids = li * P + pages
+                        kd = page_k[ids].reshape(B, s_max, kv, hd)
+                        vd = page_v[ids].reshape(B, s_max, kv, hd)
+                        kd = jnp.where(at_pos, k_new[:, None], kd)
+                        vd = jnp.where(at_pos, v_new[:, None], vd)
+                        o = _attend_dense(qg, kd, vd, pos)
+                    a = out_proj(lp["attn"], h, o)
+                h = ffn(lp, h + a)
+            return h, (k_new.reshape(B, kv * hd), v_new.reshape(B, kv * hd))
+
+        x, (ks, vs) = jax.lax.scan(
+            body, x, (layers, jnp.arange(L, dtype=jnp.int32)))
+        with jax.named_scope("cache_update"):
+            rows = (jnp.arange(L, dtype=jnp.int32)[:, None] * P + pidx
+                    ) * page_size + slot
+
+            def write(pool, new):
+                flat = pool.reshape(L * P * page_size, kv * hd)
+                return flat.at[rows.reshape(L * B)].set(
+                    new.reshape(L * B, kv * hd)).reshape(pool.shape)
+            return x, write(pool_k, ks), write(pool_v, vs)
+
+    def layers_kernel(layers, x, pool_k, pool_v, pages, pos, pidx, slot):
+        from repro.kernels.ops import _interpret_default
+        from repro.kernels.paged_attention import paged_attention
+        L, P = pool_k.shape[:2]
+
+        def body(carry, inp):
+            h, pk, pv = carry
+            lp, li = inp
+            with jax.named_scope("layer"):
+                kp = jax.lax.dynamic_index_in_dim(pk, li, 0, keepdims=False)
+                vp = jax.lax.dynamic_index_in_dim(pv, li, 0, keepdims=False)
+                kp = kp.reshape(P, page_size, kv, hd)
+                vp = vp.reshape(P, page_size, kv, hd)
+                with jax.named_scope("attn"):
+                    qg, k_new, v_new = _decode_qkv(
+                        lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps),
+                        pos, cfg)
+                    with jax.named_scope("cache_update"):
+                        kp = kp.at[pidx, slot].set(k_new.astype(kp.dtype))
+                        vp = vp.at[pidx, slot].set(v_new.astype(vp.dtype))
+                    o = paged_attention(qg[:, 0], kp, vp, pages, pos,
+                                        pages_per_step=pages_per_step,
+                                        interpret=_interpret_default())
+                    a = out_proj(lp["attn"], h, o)
+                h = ffn(lp, h + a)
+                pk = jax.lax.dynamic_update_index_in_dim(
+                    pk, kp.reshape(pk.shape[1:]), li, 0)
+                pv = jax.lax.dynamic_update_index_in_dim(
+                    pv, vp.reshape(pv.shape[1:]), li, 0)
+            return (h, pk, pv), None
+
+        (x, pool_k, pool_v), _ = jax.lax.scan(
+            body, (x, pool_k, pool_v),
+            (layers, jnp.arange(L, dtype=jnp.int32)))
+        return x, pool_k, pool_v
+
+    layers_fn = layers_kernel if use_kernel else layers_xla
 
     def decode(params, pool_k, pool_v, batch):
         cd = jnp.dtype(cfg.compute_dtype)
@@ -329,50 +412,12 @@ def build_paged_decode(model, batch_size: int, n_pages: int,
             x = jnp.take(p["embed"], batch["tokens"], axis=0).astype(cd)
         pos = batch["pos"]
         pages = batch["pages"]
-
-        def body(carry, inp):
-            h, pk, pv = carry
-            lp, li = inp
-            with jax.named_scope("layer"):
-                kp = jax.lax.dynamic_index_in_dim(pk, li, 0,
-                                                  keepdims=False)
-                vp = jax.lax.dynamic_index_in_dim(pv, li, 0,
-                                                  keepdims=False)
-                with jax.named_scope("attn"):
-                    o, kp, vp = attend(
-                        lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps),
-                        kp, vp, pages, pos)
-                    with jax.named_scope("out_proj"):
-                        B = h.shape[0]
-                        H = cfg.num_heads
-                        hd = cfg.resolved_head_dim
-                        Hp = lp["attn"]["wo"].shape[0]
-                        ow = o[:, None].reshape(B, 1, H, hd).astype(h.dtype)
-                        if Hp != H:
-                            ow = jnp.pad(ow, [(0, 0), (0, 0),
-                                              (0, Hp - H), (0, 0)])
-                        a = jnp.einsum("bsnh,nhd->bsd", ow, lp["attn"]["wo"])
-                h = h + a
-                if cfg.moe is not None:
-                    with jax.named_scope("moe"):
-                        mo, _ = moe_mod.moe_apply(
-                            lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps),
-                            cfg)
-                else:
-                    with jax.named_scope("mlp"):
-                        mo = mlp_apply(lp["mlp"],
-                                       rmsnorm(h, lp["ln2"], cfg.norm_eps))
-                h = h + mo
-                pk = jax.lax.dynamic_update_index_in_dim(pk, kp, li, 0)
-                pv = jax.lax.dynamic_update_index_in_dim(pv, vp, li, 0)
-            return (h, pk, pv), None
-
+        pidx = jnp.take_along_axis(pages, (pos // page_size)[:, None],
+                                   axis=1)[:, 0]
         stack = p["stack"]
         with jax.named_scope("layers"):
-            (x, pool_k, pool_v), _ = jax.lax.scan(
-                body, (x, pool_k, pool_v),
-                (stack["layers"],
-                 jnp.arange(cfg.num_layers, dtype=jnp.int32)))
+            x, pool_k, pool_v = layers_fn(stack["layers"], x, pool_k, pool_v,
+                                          pages, pos, pidx, pos % page_size)
         with jax.named_scope("final_norm"):
             x = rmsnorm(x, stack["ln_f"], cfg.norm_eps)
         with jax.named_scope("last_logits"):

@@ -166,6 +166,8 @@ def chunked_prefill_space(*, arch: str = "tinyllama-1.1b",
     candidate computes bit-identical logits (chunking is a pure
     schedule change), so the DSE engine is pricing pure overhead:
     context re-gather and per-chunk dispatch vs head-of-line latency.
+    The pool is the engine's lane-dense ``(L, prompt_pages + 2,
+    page_size, kv_heads*head_dim)``.
     """
     from repro.configs.registry import smoke_config
     from repro.core.dse import SearchSpace
@@ -180,11 +182,11 @@ def chunked_prefill_space(*, arch: str = "tinyllama-1.1b",
     pp, ps = prompt_pages, page_size
     if chunks is None:   # pow2 quanta plus the whole-prompt baseline
         chunks = tuple(sorted(set(_pow2_range(1, pp)) | {pp}))
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     kvd = jnp.dtype(cfg.kv_cache_dtype)
     # identity page table: prompt page i lives at pool slot i+1 (slot 0
     # is the engine's pinned null page)
-    pool_shape = (cfg.num_layers, pp + 2, ps, kv, hd)
+    pool_shape = (cfg.num_layers, pp + 2, ps,
+                  cfg.num_kv_heads * cfg.resolved_head_dim)
     tokens = jax.random.randint(jax.random.PRNGKey(seed + 1),
                                 (1, pp * ps), 0, cfg.vocab_size, jnp.int32)
 

@@ -171,9 +171,9 @@ class _FakeStepEngine(InferenceEngine):
 
         def kv_block(n_pages, toks):
             shape = (cfg.num_layers, n_pages, c.page_size,
-                     cfg.num_kv_heads, cfg.resolved_head_dim)
+                     cfg.num_kv_heads * cfg.resolved_head_dim)
             k = np.zeros(shape, np.float32)
-            k[0, :, 0, 0, 0] = toks.reshape(n_pages, c.page_size).sum(1)
+            k[0, :, 0, 0] = toks.reshape(n_pages, c.page_size).sum(1)
             return k, np.zeros(shape, np.float32)
 
         def one_hot(tok):
@@ -195,7 +195,7 @@ class _FakeStepEngine(InferenceEngine):
                 toks = np.asarray(batch["tokens"])
                 li = int(np.asarray(batch["last_idx"])[0])
                 ctx = np.asarray(batch["ctx_pages"])
-                ctx_sum = int(np.asarray(pk)[0, ctx, 0, 0, 0].sum())
+                ctx_sum = int(np.asarray(pk)[0, ctx, 0, 0].sum())
                 tok = ((ctx_sum + int(toks.sum())) * 13
                        + (cs * c.page_size + li) * 5) % _FAKE_VOCAB
                 return (one_hot(tok),) + kv_block(n, toks)
@@ -510,7 +510,7 @@ def test_chunk_prefill_step_byte_identical(tiny_model):
         params, {"tokens": jnp.asarray(toks[:, :ps]),
                  "last_idx": jnp.array([ps - 1], jnp.int32)})
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    pool = jnp.zeros((cfg.num_layers, 8, ps, kv, hd),
+    pool = jnp.zeros((cfg.num_layers, 8, ps, kv * hd),
                      jnp.dtype(cfg.kv_cache_dtype))
     pool_k, pool_v = jax.jit(build_page_scatter(1))(
         pool, pool, k0, v0, jnp.array([3], jnp.int32))
@@ -524,6 +524,44 @@ def test_chunk_prefill_step_byte_identical(tiny_model):
         v_w[:, :1], v0)
     assert jnp.array_equal(k_w[:, 1:], k_c) and jnp.array_equal(
         v_w[:, 1:], v_c)
+
+
+def test_xla_decode_writes_only_the_new_rows(tiny_model):
+    """Step-level: the XLA decode over a random lane-dense pool, lanes at
+    mixed positions and one padded lane, changes exactly the B written
+    (page, slot) rows of every layer; its logits, next tokens and pool
+    equal the paged-attention kernel path's on the same inputs."""
+    import jax.numpy as jnp
+    from repro.engine import NULL_PAGE, build_paged_decode
+    cfg, model, params = tiny_model
+    ps, n_pages, P = 16, 2, 12
+    width = cfg.num_kv_heads * cfg.resolved_head_dim
+    shape = (cfg.num_layers, P, ps, width)
+    kk, kv_ = jax.random.split(jax.random.PRNGKey(9))
+    kvd = jnp.dtype(cfg.kv_cache_dtype)
+    pool_k = jax.random.normal(kk, shape).astype(kvd)
+    pool_v = jax.random.normal(kv_, shape).astype(kvd)
+    pages = np.array([[3, 7], [5, 1], [9, 10], [NULL_PAGE, NULL_PAGE]],
+                     np.int32)                         # last lane padded
+    pos = np.array([20, 4, 31, 0], np.int32)
+    toks = np.array([[11], [22], [33], [0]], np.int32)
+    batch = {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos),
+             "pages": jnp.asarray(pages)}
+    lg, pk, pv, nt = jax.jit(build_paged_decode(
+        model, 4, n_pages, ps, use_kernel=False))(params, pool_k, pool_v,
+                                                  batch)
+    lg_k, pk_k, pv_k, nt_k = jax.jit(build_paged_decode(
+        model, 4, n_pages, ps, use_kernel=True))(params, pool_k, pool_v,
+                                                 batch)
+    assert np.array_equal(np.asarray(lg), np.asarray(lg_k))
+    assert np.array_equal(np.asarray(nt), np.asarray(nt_k))
+    written = np.zeros(shape[:3], bool)
+    written[:, pages[np.arange(4), pos // ps], pos % ps] = True
+    for old, new, ref in ((pool_k, pk, pk_k), (pool_v, pv, pv_k)):
+        old, new, ref = map(np.asarray, (old, new, ref))
+        assert np.array_equal(new, ref)
+        assert np.array_equal(new[~written], old[~written])
+        assert not np.any(np.all(new[written] == old[written], axis=-1))
 
 
 def test_engine_chunked_and_donated_bit_identical(tiny_model):
