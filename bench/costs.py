@@ -1,9 +1,12 @@
-"""Operations and bytes of the dense decoder's serving work, from shapes.
+"""Operations and bytes of the dense family's serving work, from shapes,
+and the chip's peaks and roofline, which every family shares.
 
-The same work is counted whatever implements it: a decode step reads
-every weight once and the KV of each lane's live positions, and writes
-one KV row per lane. Bytes that an implementation moves beyond that
-(a gather of every page in the page table, a copied pool) are not work.
+``families/dense.py`` serves these counts as its own; a family that
+differs brings its counts in its own file. The same work is counted
+whatever implements it: a decode step reads every weight once and the
+KV of each lane's live positions, and writes one KV row per lane.
+Bytes that an implementation moves beyond that (a gather of every page
+in the page table, a copied pool) are not work.
 FLOPs count multiply-adds as two: the matmuls of every linear layer and
 the output head, and attention's scores and weighted sum over the live
 context. Embedding lookups, norms and softmax are not counted.

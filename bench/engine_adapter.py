@@ -15,9 +15,14 @@ PERF.md; a change to any of them breaks the benchmark here first:
   token once it is on the host).
 - ``InferenceEngine._active`` / ``_prefilling``: with ``_waiting``, the
   requests the engine still holds (a check that none is left behind).
-- ``InferenceEngine.pool_k`` / ``pool_v``: the KV pool, deleted in
-  ``release`` so that the reference finds the chip's memory free.
-- ``Model.abstract_params()``: the parameter tree the weights must fill.
+- Every ``jax.Array`` made since set-up began (the weights, the KV pool
+  ``pool_k``/``pool_v``, any state a later family adds beside it) is
+  deleted in ``release``, wherever the engine holds it, so that the
+  reference finds the chip's memory free.
+- ``Model.abstract_params()``: the parameter tree the weights must fill
+  (called by each family's ``program_params``).
+
+Model families (``family.py``) configure the program's model themselves.
 """
 from __future__ import annotations
 
@@ -27,85 +32,12 @@ from pathlib import Path
 from typing import Callable, Dict, List
 
 import jax
-import jax.numpy as jnp
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.configs.registry import get_config  # noqa: E402
 from repro.engine import EngineConfig, InferenceEngine  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 from repro.telemetry import TelemetryBus  # noqa: E402
-
-import weights as W  # noqa: E402
-
-# published key -> ModelConfig field it must equal
-_SAME = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-         "num_attention_heads": "num_heads",
-         "num_key_value_heads": "num_kv_heads",
-         "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-         "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-         "tie_word_embeddings": "tie_embeddings"}
-
-
-def model_config(conf: Dict):
-    """The program's ``ModelConfig`` for a configuration file: the preset
-    it names, with its ``program_overrides``, checked key by key against
-    the published numbers the file holds."""
-    cfg = get_config(conf["program_preset"]).replace(
-        **conf.get("program_overrides", {}))
-    pub = conf["config"]
-    for key, field in _SAME.items():
-        if pub[key] != getattr(cfg, field):
-            raise ValueError(f"{conf['name']}: published {key}={pub[key]} "
-                             f"but the program runs {field}="
-                             f"{getattr(cfg, field)}")
-    if cfg.resolved_head_dim != W.dense_dims(pub)["hd"]:
-        raise ValueError(f"{conf['name']}: head_dim differs")
-    if cfg.family != "dense" or cfg.use_bias or cfg.pos_emb != "rope":
-        raise ValueError(f"{conf['name']}: not the dense family the "
-                         "reference implements")
-    return cfg
-
-
-def program_params(model: Model, dims: Dict, seed: int):
-    """The program's parameter tree filled from ``weights.py``, made on
-    the device in one jitted call, in the configuration's param dtype."""
-    cfg = model.cfg
-    want = model.abstract_params()
-    dtype = jnp.dtype(cfg.param_dtype)
-    Hp, H, Vp = cfg.resolved_padded_heads, cfg.num_heads, \
-        cfg.padded_vocab_size
-
-    def make(key):
-        lw = W.stacked_layers(key, dims, dtype)
-        g = W.global_weights(key, dims, dtype)
-        pad_h = [(0, 0), (0, 0), (0, Hp - H), (0, 0)]
-        attn = {"wq": jnp.pad(lw["q"], pad_h), "wk": lw["k"],
-                "wv": lw["v"],
-                "wo": jnp.pad(lw["o"], [(0, 0), (0, Hp - H), (0, 0),
-                                        (0, 0)])}
-        tree = {"embed": jnp.pad(g["embed"], [(0, Vp - dims["V"]), (0, 0)]),
-                "stack": {"layers": {
-                    "ln1": lw["input_norm"], "attn": attn,
-                    "ln2": lw["post_norm"],
-                    "mlp": {"wg": lw["gate"], "wi": lw["up"],
-                            "wo": lw["down"]}},
-                    "ln_f": g["final_norm"]}}
-        if "lm_head" in g:
-            tree["unembed"] = jnp.pad(g["lm_head"],
-                                      [(0, 0), (0, Vp - dims["V"])])
-        return tree
-
-    key = W.seed_key(seed)
-    got = jax.eval_shape(make, key)
-    if jax.tree_util.tree_structure(got) != \
-            jax.tree_util.tree_structure(want) or any(
-                (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in zip(
-                    jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want))):
-        raise ValueError("the program's parameter tree changed: "
-                         f"{jax.tree_util.tree_map(lambda a: a.shape, want)}")
-    return jax.block_until_ready(jax.jit(make)(key))
 
 
 def engine_config(spec: Dict) -> EngineConfig:
@@ -175,14 +107,13 @@ def decode_steps(stats: Dict) -> int:
     return stats["phases"]["decode"]["steps"]
 
 
-def release(engine: InferenceEngine, params):
-    """Free the pool and the weights on the device."""
+def release(engine: InferenceEngine, before: List[jax.Array]):
+    """Close the engine and free on the device every array made since
+    ``before`` (``jax.live_arrays()`` as set-up began): the weights, the
+    KV pool and any state a family keeps beside it, wherever it is
+    held."""
     engine.close()
-    for a in [engine.pool_k, engine.pool_v] + jax.tree_util.tree_leaves(
-            params):
-        a.delete()
-
-
-def make_model(conf: Dict) -> Model:
-    return Model(model_config(conf))
-
+    kept = {id(a) for a in before}
+    for a in jax.live_arrays():
+        if id(a) not in kept and not a.is_deleted():
+            a.delete()

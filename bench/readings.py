@@ -3,6 +3,8 @@
 A reader is ``bench/metrics/<metric name>.py`` with ``read(ctx)``
 returning a number, or ``None`` where it finds nothing to read (the
 metric is then left out of the run's line). ``ctx`` is a ``Readings``.
+A reader that counts the model's work asks the configuration's family
+(``ctx.family``, ``family.py``), never one family's counts directly.
 """
 from __future__ import annotations
 
@@ -10,6 +12,7 @@ import importlib.util
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 from devtrace import Trace
@@ -19,7 +22,8 @@ BENCH = Path(__file__).resolve().parent
 
 @dataclass
 class Readings:
-    dims: Dict                        # weights.dense_dims of the config
+    family: ModuleType                # families/<reference>.py (family.py)
+    dims: Dict                        # family.dims of the config
     peak: Dict                        # costs.peaks(device_kind)
     programs: Dict                    # phase -> regex of its program names
     counters: Dict[str, int]          # engine counters over the window

@@ -5,9 +5,12 @@
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its pieces
 are found by name: ``bench/configs/<config>.json`` (the model),
-``bench/traffic/<traffic>.json`` (the mix), ``bench/cells/<cell>.json``
-(the engine's settings, the correctness limit, the traced window) and
-``bench/metrics/<metric>.py`` (one reader per per-layer metric).
+``bench/families/<reference>.py`` (the model's family, named by the
+configuration's ``reference``: its dims, weights and counts;
+``family.py``), ``bench/traffic/<traffic>.json`` (the mix),
+``bench/cells/<cell>.json`` (the engine's settings, the correctness
+limit, the traced window) and ``bench/metrics/<metric>.py`` (one reader
+per per-layer metric).
 
 Set-up makes the weights on the device from the seed, builds the engine
 and warms every shape the seed's traffic uses. Then the engine serves
@@ -35,6 +38,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Dict, List, Optional  # noqa: E402
 
 import jax  # noqa: E402
@@ -44,9 +48,10 @@ import correct  # noqa: E402
 import costs  # noqa: E402
 import devtrace  # noqa: E402
 import engine_adapter as adapter  # noqa: E402
-import weights as W  # noqa: E402
+import family  # noqa: E402
 from readings import Readings, reader  # noqa: E402
 from traffic import Traffic  # noqa: E402
+from repro.models.model import Model  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -339,10 +344,12 @@ class Setup:
     spec: Dict
     info: Dict
     peak: Dict
+    family: ModuleType
     dims: Dict
     traffic: object
     engine: object
     params: object
+    before: List          # arrays alive as set-up began (``release``)
     compiles: CompileLog
     setup_s: float
     hook: Dict = field(default_factory=dict)
@@ -354,20 +361,22 @@ def set_up(name: str, seed: int, seconds: float, root: Path = ROOT,
            prompt_lens: Optional[List[int]] = None) -> Setup:
     """Weights from the seed, the engine, and a warm-up of every shape
     the seed's traffic uses (``prompt_lens`` widens it)."""
+    before = jax.live_arrays()
     spec = load_cell(name, root)
+    conf, eng_spec = spec["config"], spec["cell"]["engine"]
+    fam = family.load(conf["reference"], root / "bench")
     w = spec["workload"]
     info = require_accelerator(w["chips"]) if need_chip \
         else device_info(w["chips"])
     peak = peak or costs.peaks(info["kind"])
     cache_dir = enable_compile_cache(root)
     compiles = CompileLog()
-    conf, eng_spec = spec["config"], spec["cell"]["engine"]
-    dims = W.dense_dims(conf["config"])
-    model = adapter.make_model(conf)
+    dims = fam.dims(conf["config"])
+    model = Model(fam.model_config(conf))
     traffic = Traffic(spec["traffic"], seed, seconds, dims["V"])
 
     t = time.perf_counter()
-    params = adapter.program_params(model, dims, seed)
+    params = fam.program_params(model, dims, seed)
     weights_s = time.perf_counter() - t
     hook: Dict = {}
     engine = adapter.build_engine(
@@ -383,8 +392,8 @@ def set_up(name: str, seed: int, seconds: float, root: Path = ROOT,
         f"{len(eng_spec['buckets'])} decode buckets; programs by cache "
         f"outcome [count, compile s] {json.dumps(compiles.summary())}; "
         f"cache {cache_dir}")
-    return Setup(name, seed, spec, info, peak, dims, traffic, engine,
-                 params, compiles, setup_s, hook)
+    return Setup(name, seed, spec, info, peak, fam, dims, traffic, engine,
+                 params, before, compiles, setup_s, hook)
 
 
 def serve(su: Setup, seconds: float, trace: bool = False,
@@ -425,7 +434,8 @@ def per_layer(su: Setup, win: Window, root: Path):
     prefills = s1["phases"]["prefill"]["steps"] - \
         s0["phases"]["prefill"]["steps"]
     ctx = Readings(
-        dims=su.dims, peak=su.peak, programs=su.spec["cell"]["programs"],
+        family=su.family, dims=su.dims, peak=su.peak,
+        programs=su.spec["cell"]["programs"],
         counters={"decode_steps": adapter.decode_steps(s1)
                   - adapter.decode_steps(s0),
                   "decode_tokens": s1["tokens_out"] - s0["tokens_out"]
@@ -448,8 +458,8 @@ def per_layer(su: Setup, win: Window, root: Path):
 def check(su: Setup, win: Window, root: Path, control: bool = False):
     """Free the program's state, then hold a sample of the served tokens
     to the reference. Returns the gaps (``correct.served_gaps``)."""
-    adapter.release(su.engine, su.params)
-    su.engine = su.params = None
+    adapter.release(su.engine, su.before)
+    su.engine = su.params = su.before = None
     gc.collect()
     t = time.perf_counter()
     reqs = correct.sample(win.finished, su.spec["cell"]["check"]["requests"],

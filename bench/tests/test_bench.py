@@ -13,6 +13,7 @@ import os
 import re
 import subprocess
 import sys
+from collections import deque
 from pathlib import Path
 
 import jax
@@ -22,6 +23,7 @@ import pytest
 
 import costs
 import devtrace
+import family
 import tiny
 import weights as W
 from traffic import Traffic
@@ -104,15 +106,19 @@ planes {
 '''
 
 
-def test_trace_reduction_on_a_small_recorded_trace(tmp_path):
-    """Device ops in ns from the window start (1000 ns, its span):
-    [0, 1000), [500, 2000), [3000, 4000), [6000, 7000) of a 9000 ns
-    window; programs decode [0, 4000) and prefill [6000, 7000)."""
+def _fixture_trace(tmp_path):
     from jax.profiler import ProfileData
     path = tmp_path / "plugins" / "profile" / "t" / "h.xplane.pb"
     path.parent.mkdir(parents=True)
     path.write_bytes(ProfileData.text_proto_to_serialized_xspace(SMALL_TRACE))
-    tr = devtrace.load(devtrace.latest_xplane(str(tmp_path)))
+    return devtrace.load(devtrace.latest_xplane(str(tmp_path)))
+
+
+def test_trace_reduction_on_a_small_recorded_trace(tmp_path):
+    """Device ops in ns from the window start (1000 ns, its span):
+    [0, 1000), [500, 2000), [3000, 4000), [6000, 7000) of a 9000 ns
+    window; programs decode [0, 4000) and prefill [6000, 7000)."""
+    tr = _fixture_trace(tmp_path)
     assert tr.n_devices == 1
     assert tr.window_s == pytest.approx(9000e-9)
     assert tr.busy_s() == pytest.approx(4000e-9)        # 2000 + 1000 + 1000
@@ -133,14 +139,107 @@ def test_trace_reduction_on_a_small_recorded_trace(tmp_path):
 
 def test_readers_find_nothing_without_a_device_trace():
     from readings import Readings, reader
-    ctx = Readings(dims=DIMS, peak={"bf16_flops_per_s": 1.0,
-                                     "hbm_bytes_per_s": 1.0},
+    ctx = Readings(family=family.load("dense"), dims=DIMS,
+                   peak={"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0},
                    programs={"decode": "x"},
                    counters={"decode_steps": 0, "decode_tokens": 0},
                    trace=devtrace.Trace(1.0, [], []))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     for m in bench["per_layer"]:
         assert reader(m["name"])(ctx) is None, m["name"]
+
+
+def _counted(fam, dims, peak, traced, trace):
+    """``decode_roofline`` and ``serve_mfu`` read through ``fam``."""
+    from readings import Readings, reader
+    ctx = Readings(family=fam, dims=dims, peak=peak,
+                   programs={"decode": "^jit_decode",
+                             "prefill": "^jit_prefill"},
+                   counters={"decode_steps": 2, "decode_tokens": 5},
+                   trace=trace, traced=traced)
+    return {m: reader(m)(ctx) for m in ("decode_roofline", "serve_mfu")}
+
+
+UNIT_PEAK = {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e9}
+HOST_RECORDS = {"decode": [[300, 1200, 801], [301, 1201]],
+                "prefill": [256, 1024]}
+
+
+@pytest.mark.parametrize("config, parent", [
+    # read by the readers of the commit before model families, once
+    ("tiny", {"decode_roofline": 21.6, "serve_mfu": 22.933333333333334}),
+    ("granite-3-2b", {"decode_roofline": 159433.42417582418,
+                      "serve_mfu": 377594.02117992105}),
+    ("minicpm-2b", {"decode_roofline": 187779.3758241758,
+                    "serve_mfu": 406624.6627411168}),
+])
+def test_dense_readers_read_what_they_read_before_families(
+        tmp_path, config, parent):
+    """On the recorded fixture trace (one 4000 ns decode execution, a
+    9000 ns window) with fixed host records, the dense family's counts
+    give the very numbers the readers gave when they called
+    ``costs.py`` themselves."""
+    dense = family.load("dense")
+    if config == "tiny":
+        dims, peak = DIMS, UNIT_PEAK
+        traced = {"decode": [[3, 5]], "prefill": [3]}
+    else:
+        conf = json.loads((REPO / "bench" / "configs" / f"{config}.json")
+                          .read_text())
+        dims, peak = dense.dims(conf["config"]), costs.peaks("TPU v5 lite")
+        traced = HOST_RECORDS
+    assert _counted(dense, dims, peak, traced,
+                    _fixture_trace(tmp_path)) == parent
+
+
+def test_a_family_added_as_new_files_is_read_through_its_counts(
+        root, tmp_path):
+    """The tiny-twin family doubles every count, so both readers read
+    twice the dense family's numbers. Hand counts (``DIMS``, see
+    ``test_costs_against_hand_counts``): the step of lanes at 3 and 5
+    positions does 864 FLOPs and moves 472 bytes, compute-bound at
+    1e9/s: 864 ns of the decode program's 4000 ns; the window's 9000 ns
+    saw 2064 FLOPs (prefill of 3 tokens 1200, tokens at 3 and 5
+    positions 416 and 448)."""
+    twin = family.load("tiny-twin", root / "bench")
+    trace = _fixture_trace(tmp_path)
+    traced = {"decode": [[3, 5]], "prefill": [3]}
+    dense = _counted(family.load("dense"), DIMS, UNIT_PEAK, traced, trace)
+    got = _counted(twin, DIMS, UNIT_PEAK, traced, trace)
+    assert dense == {"decode_roofline": pytest.approx(100 * 864 / 4000),
+                     "serve_mfu": pytest.approx(100 * 2064 / 9000)}
+    assert got == {"decode_roofline": pytest.approx(100 * 1728 / 4000),
+                   "serve_mfu": pytest.approx(100 * 4128 / 9000)}
+    assert got == {k: 2 * v for k, v in dense.items()}
+    # memory-bound as well: 472 bytes at 1e8/s, doubled with the bytes
+    slow = {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e8}
+    assert _counted(twin, DIMS, slow, traced, trace)["decode_roofline"] \
+        == pytest.approx(100 * 2 * 4720 / 4000)
+
+
+def test_a_missing_family_fails_before_any_weight_is_made(tmp_path,
+                                                          monkeypatch):
+    import run
+    root = tiny.make_root(tmp_path)
+    b = root / "bench"
+    (b / "configs" / "lost.json").write_text(json.dumps(
+        dict(tiny.CONFIG, name="lost", reference="lost")))
+    (b / "cells" / "lost.closed.json").write_text(json.dumps(tiny.CELL))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": "lost.closed", "config": "lost",
+                               "traffic": "tiny-closed", "chips": 1,
+                               "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    def made(*_a, **_k):
+        raise AssertionError("a model or weights were made")
+    for mod, fn in ((W, "stacked_layers"), (W, "global_weights"),
+                    (run, "Model"), (run.adapter, "build_engine")):
+        monkeypatch.setattr(mod, fn, made)
+    with pytest.raises(FileNotFoundError,
+                       match=re.escape(str(b / "families" / "lost.py"))):
+        run.set_up("lost.closed", SEED, 2.0, root, need_chip=False,
+                   peak=tiny.PEAK)
 
 
 # -------------------------------------------------------------- traffic
@@ -191,7 +290,7 @@ def test_a_layer_rebuilt_alone_equals_its_slice_of_the_stack():
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    return tiny.make_root(tmp_path_factory.mktemp("bench_root"))
+    return tiny.make_root(tmp_path_factory.mktemp("bench_root"), twin=True)
 
 
 def _run(root, cell, trace=False, seed=SEED):
@@ -201,7 +300,7 @@ def _run(root, cell, trace=False, seed=SEED):
                         .perf_counter())
 
 
-@pytest.mark.parametrize("cell", tiny.CELLS)
+@pytest.mark.parametrize("cell", tiny.CELLS + (tiny.TWIN_CELL,))
 def test_a_cell_added_from_new_files_runs_and_is_correct(root, cell):
     res = _run(root, cell)
     assert list(res)[-1] == "checks"
@@ -274,6 +373,32 @@ def test_a_faulty_timed_path_is_not_correct(root, fault, monkeypatch):
         res["checks"]["max_gap"]["limit"]
 
 
+def test_release_frees_every_array_the_engine_holds(root):
+    """After ``release``, no array made by set-up or the window is left
+    on the device, though the engine object lives on: the pool, the
+    weights, and state such as a later family may add beside the pool."""
+    import engine_adapter as adapter
+    import run
+    before = jax.live_arrays()                 # held, so ids stay unique
+    su = run.set_up("tiny-dense.closed", SEED, 1.0, root, need_chip=False,
+                    peak=tiny.PEAK)
+    run.serve(su, 1.0)
+    su.engine.slot_state = {"ssm": [jnp.ones((4, 8))], "conv": jnp.ones(3)}
+
+    class Slot:                                # state held in a plain object
+        def __init__(self):
+            self.rows = jnp.zeros((2, 5))
+    su.engine.slots = deque([Slot()])
+    kept = {id(a) for a in before}
+    made = [a for a in jax.live_arrays() if id(a) not in kept]
+    assert len(made) >= 4 + len(jax.tree_util.tree_leaves(su.params))
+    del made
+    adapter.release(su.engine, su.before)
+    left = [a for a in jax.live_arrays() if id(a) not in kept]
+    assert left == [], [a.shape for a in left]
+    assert su.engine.pool_k.is_deleted()
+
+
 def test_no_accelerator_means_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
@@ -299,6 +424,9 @@ def test_benchmark_json_keeps_to_its_contract():
         assert (REPO / c["file"]).is_file()
         conf = json.loads((REPO / c["file"]).read_text())
         assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
+        for part in ("reference", "families"):
+            assert (REPO / "bench" / part / f"{conf['reference']}.py"
+                    ).is_file(), (c["name"], part)
     e2e = {m["name"]: m for m in b["end_to_end"]}
     assert e2e["setup_s"]["bound"] <= 0.25
     for w in b["workloads"]:

@@ -12,6 +12,7 @@ import pytest
 
 import devtrace
 import enginespans
+import family
 import tiny
 from readings import Readings, reader
 from test_bench import DIMS, SEED, SMALL_TRACE
@@ -80,8 +81,8 @@ def _write(tmp: Path, text: str) -> Path:
 
 
 def _ctx(trace):
-    return Readings(dims=DIMS, peak={"bf16_flops_per_s": 1e9,
-                                     "hbm_bytes_per_s": 1e8},
+    return Readings(family=family.load("dense"), dims=DIMS,
+                    peak={"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e8},
                     programs={"decode": "^jit_decode",
                               "prefill": "^jit_prefill",
                               "cache": "^jit_scatter"},
@@ -172,7 +173,7 @@ def test_engine_token_times_match_the_harness_stamps(root):
     su = run.set_up("tiny-dense.open", SEED, 2.0, root, need_chip=False,
                     peak=tiny.PEAK)
     win = run.serve(su, 2.0)
-    adapter.release(su.engine, su.params)
+    adapter.release(su.engine, su.before)
     assert win.reqs and all(len(r.token_times) == len(r.out_tokens.times)
                             for r in win.reqs)
     worst = max(abs(a - b) for r in win.reqs

@@ -3,7 +3,11 @@
 ``make_root(tmp)`` copies ``bench/`` and ``BENCHMARK.json`` into ``tmp``
 and adds a configuration, two traffic mixes, two cells and a per-layer
 metric without editing any file that was there: the way a later change
-adds a cell. The harness then runs it on the CPU.
+adds a cell. With ``twin=True`` it also adds, the same way, a model
+family of its own, ``tiny-twin`` (the dense family with its decode bytes
+and FLOPs doubled), a configuration of that family and a cell: the way
+a later change adds a configuration of another family. The harness
+then runs it on the CPU.
 """
 from __future__ import annotations
 
@@ -55,8 +59,43 @@ def read(ctx):
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 CELLS = ("tiny-dense.open", "tiny-dense.closed")
 
+TWIN_FAMILY = '''"""The dense family with twice the work: each decode
+step's bytes and every FLOP count doubled; dims, program and weights
+as dense."""
+from pathlib import Path
 
-def make_root(tmp: Path) -> Path:
+import family
+
+_dense = family.load("dense", Path(__file__).resolve().parents[1])
+dims = _dense.dims
+model_config = _dense.model_config
+program_params = _dense.program_params
+
+
+def decode_step(dims, contexts):
+    flops, nbytes = _dense.decode_step(dims, contexts)
+    return 2 * flops, 2 * nbytes
+
+
+def token_flops(dims, context):
+    return 2 * _dense.token_flops(dims, context)
+
+
+def prefill_flops(dims, prompt_len):
+    return 2 * _dense.prefill_flops(dims, prompt_len)
+'''
+TWIN_REFERENCE = '''"""The tiny-twin family's reference: the dense one."""
+from pathlib import Path
+
+import correct
+
+Reference = correct.load_reference("dense",
+                                   Path(__file__).resolve().parents[1])
+'''
+TWIN_CELL = "tiny-twin.closed"
+
+
+def make_root(tmp: Path, twin: bool = False) -> Path:
     root = Path(tmp)
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -83,5 +122,17 @@ def make_root(tmp: Path) -> Path:
         "name": "tiny_prompt_tokens", "unit": "tokens", "better": "higher",
         "source": "program_counter", "layer": "scheduler",
         "moves": "tokens_per_s", "workloads": list(CELLS)})
+    if twin:
+        add(b / "families" / "tiny-twin.py", TWIN_FAMILY)
+        add(b / "reference" / "tiny-twin.py", TWIN_REFERENCE)
+        add(b / "configs" / "tiny-twin.json", json.dumps(
+            dict(CONFIG, name="tiny-twin", reference="tiny-twin")))
+        bench["configs"].append({"name": "tiny-twin", "source": "test",
+                                 "file": "bench/configs/tiny-twin.json",
+                                 "reduced": [], "why": "test"})
+        add(b / "cells" / f"{TWIN_CELL}.json", json.dumps(CELL))
+        bench["workloads"].append({"name": TWIN_CELL, "config": "tiny-twin",
+                                   "traffic": "tiny-closed", "chips": 1,
+                                   "why": "test"})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root

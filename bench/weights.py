@@ -1,11 +1,12 @@
-"""Seeded random weights of a dense decoder, in one canonical layout.
+"""Seeded random weights of the dense family, in one canonical layout.
 
 The harness and the plain reference both draw every tensor from here,
 each on its own: the harness turns them into the program's parameter
-tree (``engine_adapter.program_params``), and the reference rebuilds one
-layer at a time from the same seed. Neither takes the other's arrays.
+tree (``families/dense.py``'s ``program_params``), and the reference
+(``reference/dense.py``) rebuilds one layer at a time from the same
+seed. Neither takes the other's arrays.
 
-Canonical tensors, per layer ``l`` (``dims`` from ``dense_dims``):
+Canonical tensors, per layer ``l`` (``dims`` from ``families/dense.py``):
 
     input_norm (d,)        post_norm (d,)      RMSNorm weight = 1 + tensor
     q (d, H, hd)   k (d, kv, hd)   v (d, kv, hd)   o (H, hd, d)
@@ -30,18 +31,6 @@ LAYER_NAMES = ("input_norm", "q", "k", "v", "o", "post_norm", "gate", "up",
                "down")
 NORM_STD = 0.1          # spread of the RMSNorm weights around 1
 EMBED_STD = 0.05        # logits spread ~ sqrt(d) * EMBED_STD
-
-
-def dense_dims(cfg: Dict) -> Dict[str, int]:
-    """Shape numbers of a configuration file's published keys."""
-    d = cfg["hidden_size"]
-    H = cfg["num_attention_heads"]
-    return {"L": cfg["num_hidden_layers"], "d": d, "H": H,
-            "kv": cfg["num_key_value_heads"],
-            "hd": cfg.get("head_dim") or d // H,
-            "ff": cfg["intermediate_size"], "V": cfg["vocab_size"],
-            "tied": bool(cfg["tie_word_embeddings"]),
-            "eps": cfg["rms_norm_eps"], "theta": cfg["rope_theta"]}
 
 
 def seed_key(seed: int):
